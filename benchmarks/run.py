@@ -38,6 +38,7 @@ from benchmarks import (
     sync_scaling,
     tau_models,
 )
+from repro.compile_cache import use_compile_cache
 
 SECTIONS = {
     "tau_models": tau_models.main,
@@ -70,6 +71,7 @@ def main() -> None:
                     help="write BENCH_*.json (bench.v1 schema) for the CI gate")
     ap.add_argument("--out-dir", default=".", help="directory for BENCH_*.json files")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         args.fast = True
 

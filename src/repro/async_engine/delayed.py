@@ -231,8 +231,8 @@ def worker_ring_combine(
     rings diverge later (per-worker gradient noise, partial-failure replay)
     without touching this contraction, and (b) it buys a shard-local gather
     with no cross-worker communication until the single psum.  On a 1-device
-    mesh this reproduces :func:`delayed_combine` bit-exactly (same gather
-    values, same tensordot contraction).
+    mesh this reproduces :func:`delayed_combine` bit-exactly (same popped
+    values, same :func:`_weighted_sum`).
     """
     K = jax.tree.leaves(ring)[0].shape[1]
     Wl = taus.shape[0]
@@ -250,15 +250,29 @@ def worker_ring_combine(
     w = (jnp.asarray(weights, jnp.float32) * live).astype(jnp.float32)
 
     def combine_leaf(r):
-        # per-worker pop: rows[w] = r[w, src_slot[w]]
-        rows = jax.vmap(
-            lambda rw, s: jax.lax.dynamic_index_in_dim(rw, s, axis=0, keepdims=False)
-        )(r, src_slot)
-        partial = jnp.tensordot(w, rows.astype(jnp.float32), axes=1)
+        # per-worker pop: row i = r[i, src_slot[i]], one dynamic slice per
+        # local worker, fused into the weighted sum (no gathered copy is held)
+        zeros = (0,) * (r.ndim - 2)
+        rows = [
+            jax.lax.dynamic_slice(r, (i, src_slot[i]) + zeros, (1, 1) + r.shape[2:])[0, 0]
+            for i in range(Wl)
+        ]
+        partial = _weighted_sum(w, rows)
         return jax.lax.psum(partial, axis_name) if axis_name is not None else partial
 
     combined = jax.tree.map(combine_leaf, ring)
     return combined, live, ring
+
+
+def _weighted_sum(w, rows):
+    """``sum_i w[i] * rows[i]`` in f32, accumulated in worker order.  XLA
+    fuses it into one pass over the rows, where a contraction would first
+    hold an f32 copy of every row (and on a TPU, at default precision, round
+    both operands to bf16)."""
+    acc = w[0] * rows[0].astype(jnp.float32)
+    for i in range(1, len(rows)):
+        acc = acc + w[i] * rows[i].astype(jnp.float32)
+    return acc
 
 
 def delayed_combine(
@@ -273,13 +287,11 @@ def delayed_combine(
 
         g = sum_w weights[w] * live[w] * g_{t - taus[w]}
 
-    so the caller never materializes the ``(W, ...)`` gather — the contraction
-    happens leaf-wise via ``tensordot`` over the gathered rows.  ``live``
+    so the caller never materializes the ``(W, ...)`` gather — the weighted
+    sum happens leaf-wise (:func:`_weighted_sum`) over the gathered rows.  ``live``
     zeroes warmup / beyond-ring workers (the paper's drop rule).
     """
     delayed, live, new_state = delayed_apply_batch(state, new_grad, taus)
     w = (jnp.asarray(weights, jnp.float32) * live).astype(jnp.float32)
-    combined = jax.tree.map(
-        lambda d: jnp.tensordot(w, d.astype(jnp.float32), axes=1), delayed
-    )
+    combined = jax.tree.map(lambda d: _weighted_sum(w, d), delayed)
     return combined, live, new_state

@@ -101,12 +101,14 @@ class DistributedAsyncEngine(_EngineBase):
             num_workers=spec.num_workers,
         )
         server.start()
-        workers = [
-            transport.start_worker(w, spec.cfg, faults=spec.faults, retry=spec.retry)
-            for w in range(spec.num_workers)
-        ]
-        self._server, self._transport, self._workers = server, transport, workers
+        # registered before any worker starts, so abort() tears the server
+        # down when a worker cannot start
+        self._server, self._transport, self._workers = server, transport, []
         self._submitted = 0
+        for w in range(spec.num_workers):
+            self._workers.append(
+                transport.start_worker(w, spec.cfg, faults=spec.faults, retry=spec.retry)
+            )
 
     def _stop_cluster(self, *, finalize: bool) -> None:
         self._server.request_stop()

@@ -333,10 +333,26 @@ class SocketTransport(_CloseableBase):
     def start_worker(self, worker_id: int, cfg: Any, *, faults=None, retry=None):
         """Spawn one worker PROCESS against ``self.address``; returns the
         (daemon, already-started) process.  spawn, not fork — forking an
-        initialized JAX runtime deadlocks."""
+        initialized JAX runtime deadlocks.
+
+        Refused on an accelerator host: each worker process jits its own
+        gradient, and the accelerator already belongs to this process (a
+        chip serves one process at a time), so the children would fail or
+        hang.  There the workers run as threads: ``transport="inproc"``.
+        """
         import multiprocessing
 
+        import jax
+
         from repro.distributed.worker import socket_worker_main
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"socket transport: worker processes cannot share the {backend!r} "
+                "device this process holds; use transport='inproc' on an "
+                "accelerator host"
+            )
 
         mp = multiprocessing.get_context("spawn")
         p = mp.Process(

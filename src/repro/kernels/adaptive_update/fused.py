@@ -8,12 +8,20 @@ links enter as SCALAR factors (``f_stale``/``f_keep``/``f_clip``), so the
 "± clip" variants reuse the same kernels: the norm reduction happens outside
 (it is a second data pass by nature) and only its scalar result is fused in.
 
-Every (BLOCK_ROWS, LANES) VMEM tile of ``p``/``g``/state is read once and
-written once — the whole server update is a single HBM pass no matter how
-many links the chain has, vs one read+write pass PER LINK for the link-by-link
+Every ``_TILE``-element block of ``p``/``g``/state is read once and written
+once — the whole server update is a single HBM pass no matter how many links
+the chain has, vs one read+write pass PER LINK for the link-by-link
 ``tree.map`` execution.  Scalars ride as (1, 1) SMEM-friendly tiles exactly
 like the original ``adaptive_update`` kernel, so one compiled kernel serves
 every staleness value / clip factor / bias-correction step.
+
+The kernels work on the flat buffers as they are stored: 1-D ``(N,)``
+params / gradient / state and the ``(K, N)`` ring, blocked ``_TILE``
+elements at a time over a ``cdiv(N, _TILE)`` grid, so a ragged tail is the
+partial last block (masked by Pallas) and nothing is padded, reshaped or
+sliced around the launch.  Params, ring and optimizer state are aliased
+input -> output (``input_output_aliases``): under a donating jit the kernel
+updates them in place, and the step holds one copy of each.
 
 Scalar factors are applied sequentially in link order (never pre-multiplied):
 float multiplication is not associative, and bit-equality with the unfused
@@ -124,46 +132,50 @@ _KERNELS = {
 }
 
 
-def _to_tiles(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    pad = (-n) % _TILE
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(-1, LANES), n
+_SCALAR = pl.BlockSpec((1, 1), lambda i: (0, 0))
+_VEC = pl.BlockSpec((_TILE,), lambda i: (i,))
+
+
+def _grid(n: int) -> tuple[int]:
+    return (pl.cdiv(n, _TILE),)
+
+
+def _ring_block(K: int) -> pl.BlockSpec:
+    return pl.BlockSpec((K, _TILE), lambda i: (0, i))
+
+
+def _scalar_tiles(kind: str, scalars) -> list:
+    return [jnp.asarray(scalars[k], jnp.float32).reshape(1, 1) for k in SCALAR_ORDER[kind]]
+
+
+def _shape_of(x) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret"))
-def fused_chain_call(kind: str, p, g, bufs, scalars, *, interpret: bool = True):
-    """One Pallas launch for a fused chain step on flat 1-D buffers.
+def fused_chain_call(kind: str, p, g, bufs, scalars, *, interpret: bool = False):
+    """One Pallas launch for a fused chain step on flat ``(N,)`` buffers.
 
     ``bufs`` is the family's flat state tuple (see ``_KERNELS``), ``scalars``
     the f32 scalar bundle keyed per ``SCALAR_ORDER[kind]``.  Returns
-    ``(p_new, new_bufs)`` with the same flat shapes.
+    ``(p_new, new_bufs)``; ``p`` and ``bufs`` are aliased to them.
     """
     kernel, n_bufs = _KERNELS[kind]
     bufs = tuple(bufs)
     assert len(bufs) == n_bufs, f"{kind} expects {n_bufs} state buffers, got {len(bufs)}"
-    p2d, n = _to_tiles(p)
-    g2d, _ = _to_tiles(g.astype(jnp.float32))
-    buf2d = [_to_tiles(b)[0] for b in bufs]
-    R = p2d.shape[0]
-    grid = (R // BLOCK_ROWS,)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    tile = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    svals = [jnp.asarray(scalars[k], jnp.float32).reshape(1, 1) for k in SCALAR_ORDER[kind]]
-    out2d = pl.pallas_call(
+    svals = _scalar_tiles(kind, scalars)
+    S = len(svals)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[scalar_spec] * len(svals) + [tile] * (2 + n_bufs),
-        out_specs=[tile] * (1 + n_bufs),
-        out_shape=[jax.ShapeDtypeStruct(p2d.shape, p2d.dtype)]
-        + [jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buf2d],
+        grid=_grid(p.shape[0]),
+        in_specs=[_SCALAR] * S + [_VEC] * (2 + n_bufs),
+        out_specs=[_VEC] * (1 + n_bufs),
+        out_shape=[_shape_of(p)] + [_shape_of(b) for b in bufs],
+        # p -> p_new, bufs[j] -> new_bufs[j]  (operand g at S + 1 is read-only)
+        input_output_aliases={S: 0, **{S + 2 + j: 1 + j for j in range(n_bufs)}},
         interpret=interpret,
-    )(*svals, p2d, g2d, *buf2d)
-    p_new = out2d[0].reshape(-1)[:n].reshape(p.shape)
-    new_bufs = tuple(o.reshape(-1)[:n].reshape(b.shape) for o, b in zip(out2d[1:], bufs))
-    return p_new, new_bufs
+    )(*svals, p, g, *bufs)
+    return out[0], tuple(out[1:])
 
 
 def fused_chain_flat(
@@ -203,9 +215,9 @@ def fused_chain_flat(
 # One-launch async tick: ring push + weighted combine fused into the chain
 # ---------------------------------------------------------------------------
 #
-# The tick kernels take the whole flat-resident delayed ring as a (K, rows,
-# LANES) operand tiled over the SAME row grid as p/g/state: each grid step
-# owns a (K, BLOCK_ROWS, LANES) ring block, pushes the fresh gradient into
+# The tick kernels take the whole flat-resident delayed ring as its (K, N)
+# operand, blocked over the SAME grid as p/g/state: each grid step owns a
+# (K, _TILE) ring block, pushes the fresh gradient into
 # slot t%K via a one-hot select, contracts the K slots against the slot-folded
 # combine weights, and feeds the result straight into the chain body — params,
 # ring slot and optimizer state are all written in the same pass, so the whole
@@ -214,7 +226,7 @@ def fused_chain_flat(
 #
 # Slot folding: the per-worker weights w[w] land on ring slots as
 # ``w_slot[k] = sum_{w: slot(tau_w)=k} w[w] * live[w]`` — workers sharing a
-# slot fold BEFORE the multiply, whereas the unfused tensordot sums after.
+# slot fold BEFORE the multiply, whereas the unfused weighted sum adds after.
 # Same value to f32 round-off, not bitwise; the production CPU/GPU path
 # therefore runs ``fused_tick_ref`` (exact composition of the unfused ops)
 # and the Pallas tick is tolerance-tested under the ``pallas`` mark.
@@ -222,13 +234,11 @@ def fused_chain_flat(
 
 def _tick_combine(push_ref, wsl_ref, g_ref, r_ref, r_out_ref):
     """Push the fresh gradient into the ring block and combine the K slots."""
-    g = g_ref[...]  # (BLOCK_ROWS, LANES), already in ring dtype
-    r = r_ref[...]  # (K, BLOCK_ROWS, LANES)
-    oh = push_ref[...][:, :, None]  # (K, 1, 1)
-    r_new = jnp.where(oh > 0, g[None, :, :], r)
+    r = r_ref[...]  # (K, _TILE)
+    g = g_ref[...].astype(r.dtype)  # (_TILE,): the push stores the ring-dtype cast
+    r_new = jnp.where(push_ref[...] > 0, g[None, :], r)  # push: (K, 1) one-hot
     r_out_ref[...] = r_new
-    w = wsl_ref[...][:, :, None]  # (K, 1, 1) slot-folded weights
-    return jnp.sum(w * r_new.astype(jnp.float32), axis=0)
+    return jnp.sum(wsl_ref[...] * r_new.astype(jnp.float32), axis=0)  # (K, 1) weights
 
 
 def _sgd_tick_kernel(
@@ -276,14 +286,6 @@ _TICK_KERNELS = {
 }
 
 
-def _ring_to_tiles(ring: jnp.ndarray) -> tuple[jnp.ndarray, int]:
-    K, n = ring.shape
-    pad = (-n) % _TILE
-    if pad:
-        ring = jnp.pad(ring, ((0, 0), (0, pad)))
-    return ring.reshape(K, -1, LANES), n
-
-
 def _slot_weights(K: int, step, taus, weights):
     """Trace the push one-hot, the slot-folded combine weights and the drop
     mask for one tick — (K, 1) operand shapes, matching the scalar tiles."""
@@ -298,47 +300,34 @@ def _slot_weights(K: int, step, taus, weights):
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret"))
-def fused_tick_call(kind: str, p, g, bufs, scalars, ring, push, w_slot, *, interpret: bool = True):
-    """One Pallas launch for a whole async tick on flat 1-D buffers.
+def fused_tick_call(kind: str, p, g, bufs, scalars, ring, push, w_slot, *, interpret: bool = False):
+    """One Pallas launch for a whole async tick on flat ``(N,)`` buffers.
 
     ``ring`` is the flat ``(K, N)`` delayed ring; ``push`` / ``w_slot`` the
     ``(K, 1)`` one-hot push selector and slot-folded combine weights from
-    :func:`_slot_weights`.  Returns ``(p_new, new_bufs, new_ring)``.
+    :func:`_slot_weights`.  Returns ``(p_new, new_bufs, new_ring)``; ``p``,
+    ``bufs`` and ``ring`` are aliased to them.
     """
     kernel, n_bufs = _TICK_KERNELS[kind]
     bufs = tuple(bufs)
     assert len(bufs) == n_bufs, f"{kind} expects {n_bufs} state buffers, got {len(bufs)}"
-    p2d, n = _to_tiles(p)
-    g2d, _ = _to_tiles(g.astype(ring.dtype))  # push stores the ring-dtype cast
-    ring3d, _ = _ring_to_tiles(ring)
-    buf2d = [_to_tiles(b)[0] for b in bufs]
     K = ring.shape[0]
-    R = p2d.shape[0]
-    grid = (R // BLOCK_ROWS,)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    kvec_spec = pl.BlockSpec((K, 1), lambda i: (0, 0))
-    tile = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    ring_tile = pl.BlockSpec((K, BLOCK_ROWS, LANES), lambda i: (0, i, 0))
-    svals = [jnp.asarray(scalars[k], jnp.float32).reshape(1, 1) for k in SCALAR_ORDER[kind]]
-    out2d = pl.pallas_call(
+    svals = _scalar_tiles(kind, scalars)
+    S = len(svals)
+    kvec = pl.BlockSpec((K, 1), lambda i: (0, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[scalar_spec] * len(svals)
-        + [kvec_spec, kvec_spec]
-        + [tile, tile, ring_tile]
-        + [tile] * n_bufs,
-        out_specs=[tile, ring_tile] + [tile] * n_bufs,
-        out_shape=[
-            jax.ShapeDtypeStruct(p2d.shape, p2d.dtype),
-            jax.ShapeDtypeStruct(ring3d.shape, ring3d.dtype),
-        ]
-        + [jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buf2d],
+        grid=_grid(p.shape[0]),
+        in_specs=[_SCALAR] * S + [kvec, kvec, _VEC, _VEC, _ring_block(K)] + [_VEC] * n_bufs,
+        out_specs=[_VEC, _ring_block(K)] + [_VEC] * n_bufs,
+        out_shape=[_shape_of(p), _shape_of(ring)] + [_shape_of(b) for b in bufs],
+        # p -> p_new, ring -> new_ring, bufs[j] -> new_bufs[j]
+        input_output_aliases={
+            S + 2: 0, S + 4: 1, **{S + 5 + j: 2 + j for j in range(n_bufs)}
+        },
         interpret=interpret,
-    )(*svals, push, w_slot, p2d, g2d, ring3d, *buf2d)
-    p_new = out2d[0].reshape(-1)[:n].reshape(p.shape)
-    new_ring = out2d[1].reshape(K, -1)[:, : ring.shape[1]]
-    new_bufs = tuple(o.reshape(-1)[:n].reshape(b.shape) for o, b in zip(out2d[2:], bufs))
-    return p_new, new_bufs, new_ring
+    )(*svals, push, w_slot, p, g, ring, *bufs)
+    return out[0], tuple(out[2:]), out[1]
 
 
 def _combine_kernel(push_ref, wsl_ref, g_ref, r_ref, g_out_ref, r_out_ref):
@@ -346,34 +335,23 @@ def _combine_kernel(push_ref, wsl_ref, g_ref, r_ref, g_out_ref, r_out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_combine_call(g, ring, push, w_slot, *, interpret: bool = True):
+def fused_combine_call(g, ring, push, w_slot, *, interpret: bool = False):
     """One Pallas launch for push + weighted combine only: ``(g_eff, new_ring)``.
 
     The two-launch tick of the clip variant (norm reduction between combine
-    and apply) and of the sharded engine (combine runs per-shard under
-    shard_map, apply on the merged g_eff).
+    and apply).  ``ring`` is aliased to ``new_ring``.
     """
-    g2d, n = _to_tiles(g.astype(ring.dtype))
-    ring3d, _ = _ring_to_tiles(ring)
     K = ring.shape[0]
-    R = g2d.shape[0]
-    grid = (R // BLOCK_ROWS,)
-    kvec_spec = pl.BlockSpec((K, 1), lambda i: (0, 0))
-    tile = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    ring_tile = pl.BlockSpec((K, BLOCK_ROWS, LANES), lambda i: (0, i, 0))
-    g_eff2d, ring_out = pl.pallas_call(
+    kvec = pl.BlockSpec((K, 1), lambda i: (0, 0))
+    g_eff, new_ring = pl.pallas_call(
         _combine_kernel,
-        grid=grid,
-        in_specs=[kvec_spec, kvec_spec, tile, ring_tile],
-        out_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)), ring_tile],
-        out_shape=[
-            jax.ShapeDtypeStruct(g2d.shape, jnp.float32),
-            jax.ShapeDtypeStruct(ring3d.shape, ring3d.dtype),
-        ],
+        grid=_grid(g.shape[0]),
+        in_specs=[kvec, kvec, _VEC, _ring_block(K)],
+        out_specs=[_VEC, _ring_block(K)],
+        out_shape=[jax.ShapeDtypeStruct(g.shape, jnp.float32), _shape_of(ring)],
+        input_output_aliases={3: 1},
         interpret=interpret,
-    )(push, w_slot, g2d, ring3d)
-    g_eff = g_eff2d.reshape(-1)[:n]
-    new_ring = ring_out.reshape(K, -1)[:, : ring.shape[1]]
+    )(push, w_slot, g, ring)
     return g_eff, new_ring
 
 

@@ -66,7 +66,7 @@ def fused_tick_ref(kind: str, p, g, bufs, s, ring, step, taus, weights):
     Composes the proven ring ops (:func:`repro.async_engine.delayed
     .delayed_combine` on the bare ``(K, N)`` ring — a single-leaf pytree) with
     :func:`fused_chain_ref`, so the tick is bit-identical to the unfused
-    push + gather + tensordot + link-by-link pipeline.  This IS the production
+    push + gather + weighted sum + link-by-link pipeline.  This IS the production
     CPU/GPU lowering of ``flat_tick_step``; the Pallas tick kernel folds the
     per-worker weights onto ring slots instead (different float association
     when workers share a slot) and is tolerance-tested against this.

@@ -7,6 +7,7 @@ jax device state (device count locks on first use).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_small_mesh", "make_workers_mesh", "HARDWARE"]
 
@@ -21,16 +22,23 @@ HARDWARE = {
 }
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model's sharding
+    constraints and the worker ``shard_map`` are written for Auto axes, and
+    ``make_mesh`` defaults to ``Explicit`` ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_small_mesh(data: int = 2, model: int = 4):
     """Reduced mesh for CI dry-run tests (8 fake host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_workers_mesh(devices: int | None = None):
@@ -43,4 +51,4 @@ def make_workers_mesh(devices: int | None = None):
     trajectory bit-exactly (regression-tested in tests/test_scenarios.py).
     """
     n = jax.local_device_count() if devices is None else devices
-    return jax.make_mesh((n,), ("workers",))
+    return _auto_mesh((n,), ("workers",))

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.async_engine.events import EventSimConfig, simulate_staleness_trace
 from repro.bench_schema import write_bench_json
+from repro.compile_cache import use_compile_cache
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced
 from repro.core.staleness import CMP, Geometric, Poisson
 from repro.core.step_size import make_schedule
@@ -217,6 +218,7 @@ def main(argv=None) -> None:
                     help="CI cell set (2 archs x 2 models x 2 optims)")
     ap.add_argument("--out", default="BENCH_scenarios.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.smoke:
         args.archs = ",".join(SMOKE_ARCHS)
         args.staleness = ",".join(SMOKE_STALENESS)

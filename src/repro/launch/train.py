@@ -3,9 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b \
         --steps 200 --batch 8 --seq 256 --reduced --async_psgd --strategy poisson_momentum
 
-On a real TPU slice this builds the production mesh and pjits the step with
-the Megatron/FSDP shardings from :mod:`repro.sharding.specs`; on CPU (CI) the
-``--reduced`` flag trains the reduced config on the default 1-device mesh.
+The step runs on one device: the launcher builds no mesh (only
+``sharded_async`` runs, which the scenario matrix drives, span a ``workers``
+mesh).  Full configs are sized for the chip; on CPU (CI) the ``--reduced``
+flag trains the reduced config.
 The MindTheStep configuration mirrors the paper's Fig. 3 protocol: Poisson
 staleness model with lambda = m, eq. (17) step size with K = alpha_c (the
 implicit-momentum magnitude, in step-size units), normalization (eq. 26)
@@ -32,11 +33,44 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced
 from repro.distributed.transport import transport_kinds
 from repro.optim import transform as T
 from repro.run import CheckpointHook, LogHook, RunSpec, run
 from repro.training import default_adapt_setup
+
+
+def mindthestep_pipeline(
+    lr: float,
+    workers: int,
+    ring: int,
+    *,
+    momentum: float | None = None,
+    fused: bool = False,
+    staleness: bool = True,
+):
+    """The launcher's update pipeline and its jit-resident adaptation state.
+
+    Base links: ``fused_apply(lr, mu)`` with ``fused`` (mu defaults to 0.9),
+    ``scale(-lr) -> trace(momentum)`` with a momentum, else ``scale(-lr)``.
+    With ``staleness`` (the async and live engines) a ``scale_by_staleness``
+    link leads the chain and ``adapt`` is its :class:`AdaptState`; otherwise
+    ``adapt`` is None.  Returns ``(pipeline, adapt)``.
+    """
+    if fused:
+        base_links = (T.fused_apply(lr, 0.9 if momentum is None else momentum),)
+    elif momentum is not None:
+        base_links = (T.scale(-lr), T.trace(momentum))
+    else:
+        base_links = (T.scale(-lr),)
+    if not staleness:
+        return T.chain(*base_links), None
+    sched, _model, adapt = default_adapt_setup(lr, workers, ring)
+    # m enables the online estimator; its tau_max must cover adapt's so a
+    # refreshed table always fills the jit-resident one.
+    link = T.scale_by_staleness(sched, lr, m=workers, tau_max=adapt.tau_max)
+    return T.chain(link, *base_links), adapt
 
 
 def main():
@@ -93,6 +127,7 @@ def main():
                          "(bit-identical to the uninterrupted run)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint_dir")
     if args.checkpoint_every and not args.checkpoint_dir:
@@ -116,35 +151,21 @@ def main():
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-
-    # -- base-update links (the optimizer) -----------------------------------
-    if args.fused:
-        mu = 0.9 if args.momentum is None else args.momentum
-        base_links = (T.fused_apply(args.lr, mu),)
-    elif args.momentum is not None:
-        base_links = (T.scale(-args.lr), T.trace(args.momentum))
-    else:
-        base_links = (T.scale(-args.lr),)
-
-    # -- staleness link + the run spec ----------------------------------------
-    adapt = None
-    if use_staleness:
-        sched, model, adapt = default_adapt_setup(args.lr, args.workers, args.ring)
-        # m enables the online estimator; its tau_max must cover adapt's so a
-        # refreshed table always fills the jit-resident one.
-        link = T.scale_by_staleness(sched, args.lr, m=args.workers, tau_max=adapt.tau_max)
-        pipeline = T.chain(link, *base_links)
-    else:
-        pipeline = T.chain(*base_links)
+    pipeline, adapt = mindthestep_pipeline(
+        args.lr, args.workers, args.ring,
+        momentum=args.momentum, fused=args.fused, staleness=use_staleness,
+    )
 
     import jax
 
     from repro.async_engine.delayed import flat_size
     from repro.training import init_params
 
-    # Pre-init the params (same key discipline as init_train_state) so the
-    # header can report the size without a second (TPU-scale) model init.
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
+    # The size comes from shapes alone: the engine initializes the params
+    # from the seed, so no second full-width copy stays alive in the spec.
+    n_params = flat_size(
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(args.seed))
+    )
 
     spec = RunSpec(
         cfg=cfg,
@@ -170,10 +191,8 @@ def main():
         worker_timeout=args.worker_timeout,
         refresh_every=args.refresh_every,
         seed=args.seed,
-        params=params,
     )
 
-    n_params = flat_size(params)
     print(f"arch={cfg.name} params={n_params / 1e6:.1f}M mode={mode} "
           f"fused={args.fused} fuse={args.fuse}")
 

@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import Params, _act, truncated_normal
-from repro.sharding.ctx import current_rules, shard_map_compat
+from repro.sharding.ctx import current_rules
 
 
 def init_moe(key, cfg) -> Params:
@@ -178,7 +178,7 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, jnp.ndarray]
             }
 
             @functools.partial(
-                shard_map_compat,
+                jax.shard_map,
                 mesh=mesh,
                 in_specs=(wspec, bspec),
                 out_specs=(bspec, P()),
@@ -218,7 +218,7 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, jnp.ndarray]
             }
 
             @functools.partial(
-                shard_map_compat,
+                jax.shard_map,
                 mesh=mesh,
                 in_specs=(wspec, bspec),
                 out_specs=(bspec, P()),

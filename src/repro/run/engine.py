@@ -91,6 +91,13 @@ class Engine(Protocol):
     def liveness(self) -> dict: ...
 
 
+def _buffer_ptr(x) -> int | None:
+    """Device address of an array's first shard (identifies shared buffers)."""
+    if not isinstance(x, jax.Array) or x.size == 0:
+        return None
+    return x.addressable_shards[0].data.unsafe_buffer_pointer()
+
+
 def _refresher_of(pipeline):
     """The refresh-capable handle of ``pipeline``: a scale_by_staleness link
     (possibly inside a chain) or a legacy MindTheStep-style wrapper.  Shares
@@ -141,15 +148,20 @@ class _EngineBase:
         return jax.jit(counting)
 
     def _own(self, state):
-        """Copy the built state when ticks will donate it: a donated buffer
-        is deleted after the call, and the built state shares arrays with the
-        spec (``spec.params``, ``spec.adapt``) that must survive this run —
-        and the next run built from the same spec."""
-        if self._donate_state and self.spec.fuse:
-            import jax.numpy as jnp
+        """Copy the leaves of the built state that share a device buffer
+        with the spec (``spec.params``, ``spec.adapt``) when ticks will
+        donate the state: a donated buffer is deleted after the call, and
+        the spec's arrays must survive this run — and the next run built
+        from the same spec.  Leaves the build made fresh (the packed params,
+        the zero ring, the optimizer state) are donated as they are, so a
+        full-width state is held once, not twice."""
+        if not (self._donate_state and self.spec.fuse):
+            return state
+        import jax.numpy as jnp
 
-            return jax.tree.map(jnp.copy, state)
-        return state
+        held = {_buffer_ptr(x) for x in jax.tree.leaves((self.spec.params, self.spec.adapt))}
+        held.discard(None)
+        return jax.tree.map(lambda x: jnp.copy(x) if _buffer_ptr(x) in held else x, state)
 
     def _build(self, key):
         """Initial state from a PRNG key (the key stays an *argument* so

@@ -21,24 +21,10 @@ __all__ = [
     "ShardingRules",
     "use_sharding_rules",
     "shard_activation",
-    "shard_map_compat",
     "current_rules",
     "DEFAULT_RULES",
 ]
 
-
-def shard_map_compat(fun, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: older releases ship it under
-    ``jax.experimental.shard_map``, and the replication-check flag was
-    renamed ``check_rep`` -> ``check_vma`` independently of the top-level
-    promotion — so feature-detect the kwarg, not just the attribute."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    flag = "check_vma" if "check_vma" in inspect.signature(sm).parameters else "check_rep"
-    return sm(fun, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{flag: check_vma})
 
 # logical axis -> mesh axis (or tuple of mesh axes, or None)
 DEFAULT_RULES: dict[str, object] = {

@@ -382,13 +382,12 @@ def merge_worker_hist(adapt: WorkerAdaptState, mesh=None, axis_name: str = "work
         return jnp.sum(adapt.hist, axis=0)
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.ctx import shard_map_compat
-
-    merged = shard_map_compat(
+    merged = jax.shard_map(
         lambda h: jax.lax.psum(jnp.sum(h, axis=0), axis_name),
         mesh=mesh,
         in_specs=P(axis_name, None),
         out_specs=P(None),
+        check_vma=False,
     )(adapt.hist)
     return merged
 
@@ -410,7 +409,9 @@ def worker_host_refresh(
     drains it into the estimator, refits the policy table, and returns a
     same-shape :class:`WorkerAdaptState`.  The per-worker samplers (CDF rows,
     traces) model the ENVIRONMENT and stay fixed, mirroring
-    :func:`host_refresh`'s fixed-sampler default.
+    :func:`host_refresh`'s fixed-sampler default.  The new table and the
+    reset histogram keep the placement of the leaves they replace, so the
+    compiled sharded step sees the same input types and does not retrace.
     """
     counts = np.asarray(jax.device_get(merge_worker_hist(adapt, mesh)))
     table = _refit_alpha_table(
@@ -418,9 +419,9 @@ def worker_host_refresh(
         normalize=normalize, logger=logger, n_bins=adapt.alpha_table.shape[0],
     )
     return WorkerAdaptState(
-        alpha_table=table,
+        alpha_table=jax.device_put(table, adapt.alpha_table.sharding),
         tau_cdf=adapt.tau_cdf,
         tau_trace=adapt.tau_trace,
         use_trace=adapt.use_trace,
-        hist=jnp.zeros_like(adapt.hist),
+        hist=jax.device_put(jnp.zeros(adapt.hist.shape, adapt.hist.dtype), adapt.hist.sharding),
     )

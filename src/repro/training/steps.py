@@ -467,8 +467,6 @@ def make_step(
     assert mesh is not None, "sharded_async mode needs the workers mesh"
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.ctx import shard_map_compat
-
     def train_step(state: TrainState, batch) -> tuple[TrainState, dict]:
         adapt = state.adapt
         ring = state.delayed
@@ -512,7 +510,7 @@ def make_step(
             )
             return g_eff, new_ring, new_hist, stats
 
-        g_eff, new_ring, new_hist, stats = shard_map_compat(
+        g_eff, new_ring, new_hist, stats = jax.shard_map(
             tick,
             mesh=mesh,
             in_specs=(
@@ -521,6 +519,7 @@ def make_step(
                 P(axis_name, None), P(),
             ),
             out_specs=(grad_specs, ring_specs, P(axis_name, None), P()),
+            check_vma=False,
         )(
             ring.ring, ring.step, grads, u, adapt.tau_cdf,
             adapt.tau_trace, adapt.use_trace, adapt.hist, adapt.alpha_table,
@@ -533,10 +532,25 @@ def make_step(
             use_trace=adapt.use_trace,
             hist=new_hist,
         )
-        ctx = T.StepContext(
-            adapt=new_adapt, rng=rng, axis_name=axis_name, staleness_applied=True
-        )
-        new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
+        if fused_flat:
+            # XLA cannot partition a Pallas kernel: every device runs the
+            # fused apply on its own replica of params, g_eff and opt state.
+            # The fused body reads no ctx data once staleness is applied.
+            new_params, new_opt = jax.shard_map(
+                lambda g, opt, p: apply_fn(
+                    g, opt, p,
+                    T.StepContext(axis_name=axis_name, staleness_applied=True),
+                ),
+                mesh=mesh,
+                in_specs=(P(), P(), P()),
+                out_specs=(P(), P()),
+                check_vma=False,
+            )(g_eff, state.opt_state, state.params)
+        else:
+            ctx = T.StepContext(
+                adapt=new_adapt, rng=rng, axis_name=axis_name, staleness_applied=True
+            )
+            new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1,
             rng=rng, delayed=WorkerRing(ring=new_ring, step=ring.step + 1),
@@ -586,19 +600,23 @@ def init_sharded_async_state(
     *,
     ring: int,
     adapt: WorkerAdaptState,
+    mesh,
     params: Any | None = None,
-    mesh=None,
     fuse: bool = False,
     ring_dtype: Any = None,
 ) -> TrainState:
     """TrainState for the sharded engine: per-worker rings + WorkerAdaptState.
 
     The worker count is taken from ``adapt``; ring leaves are (W, K, ...).
-    Pass ``mesh`` (with a ``workers`` axis) to place every worker-axis leaf
-    with :func:`repro.sharding.specs.worker_shardings` up front — otherwise
-    the first compiled step pays a one-time reshard.  ``fuse=True`` builds
-    the fused layout (flat opt state + one (W, K, N) ring buffer) for a
-    fuseable pipeline; pair it with ``make_step(..., fuse=True)``.
+    Every leaf is placed on ``mesh``, the workers mesh the step runs on:
+    the worker-axis leaves (rings, tau samplers, histograms) split over
+    ``workers`` by :func:`repro.sharding.specs.worker_shardings`, everything
+    else (params, optimizer state, step counters, rng, alpha table)
+    replicated.  That placement is what the step returns, so the state is a
+    fixed point of the compiled tick and the second tick hits the jit cache.
+    ``fuse=True`` builds the fused layout (flat opt state + one (W, K, N)
+    ring buffer) for a fuseable pipeline; pair it with
+    ``make_step(..., fuse=True)``.
     """
     state = init_train_state(
         key, cfg, opt, async_ring=0, adapt=adapt, params=params, fuse=fuse
@@ -606,22 +624,31 @@ def init_sharded_async_state(
     init_wring = (
         init_flat_worker_ring if fuse and _fused_form(opt) is not None else init_worker_ring
     )
-    wring = init_wring(state.params, ring, adapt.num_workers, dtype=ring_dtype)
-    if mesh is not None and "workers" in getattr(mesh, "axis_names", ()):
-        from repro.sharding.specs import worker_shardings
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-        wring = dataclasses.replace(
-            wring, ring=jax.device_put(wring.ring, worker_shardings(wring.ring, mesh))
-        )
-        placed = {
-            f: jax.device_put(v, worker_shardings(v, mesh))
-            for f, v in (
-                ("tau_cdf", adapt.tau_cdf), ("tau_trace", adapt.tau_trace),
-                ("use_trace", adapt.use_trace), ("hist", adapt.hist),
-            )
-        }
-        state = dataclasses.replace(state, adapt=dataclasses.replace(adapt, **placed))
-    return dataclasses.replace(state, delayed=wring)
+    from repro.sharding.specs import worker_shardings
+
+    replicated = NamedSharding(mesh, P())
+
+    # The rings are made on the mesh, each device holding only its workers'
+    # rows: made whole on one device first, the (W, K, N) ring of a real
+    # model does not fit there.
+    shapes = jax.eval_shape(lambda: state.params)
+
+    def make_ring():
+        return init_wring(shapes, ring, adapt.num_workers, dtype=ring_dtype)
+
+    ring_shardings = WorkerRing(
+        ring=worker_shardings(jax.eval_shape(make_ring).ring, mesh), step=replicated
+    )
+    wring = jax.jit(make_ring, out_shardings=ring_shardings)()
+    state = dataclasses.replace(state, delayed=wring)
+
+    shardings = jax.tree.map(lambda _: replicated, state)
+    shardings.delayed = ring_shardings
+    for f in ("tau_cdf", "tau_trace", "use_trace", "hist"):
+        setattr(shardings.adapt, f, worker_shardings(getattr(adapt, f), mesh))
+    return jax.device_put(state, shardings)
 
 
 def make_serve_step(cfg) -> Callable:

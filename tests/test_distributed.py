@@ -451,12 +451,15 @@ class TestDistributedEngine:
             [np.asarray(t, np.int64) for t in per_worker],
             cdf_support=RING,
         )
+        # One fixed batch: the loss of a fresh random batch each step moves
+        # by more than 24 small steps do, so only a fixed one shows training.
+        batch = spec.batch_fn(0)
         replay = RunSpec(
             cfg=tiny_cfg,
             pipeline=_pipeline(workers),
             mode="sharded_async",
             num_steps=steps,
-            batch_fn=spec.batch_fn,
+            batch_fn=lambda t: batch,
             num_workers=workers,
             ring=RING,
             adapt=adapt,
@@ -502,6 +505,21 @@ class TestSocketTransport:
         assert int(np.asarray(res.state.step)) == 3
         taus = load_trace(path)
         assert len(taus) == 3
+
+    def test_worker_processes_refused_on_accelerator(self, tiny_cfg, monkeypatch):
+        """A chip serves one process: on an accelerator backend the socket
+        transport refuses to spawn JAX worker processes and names inproc."""
+        import jax
+
+        from repro.distributed.transport import SocketTransport
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        transport = SocketTransport()
+        try:
+            with pytest.raises(RuntimeError, match="inproc"):
+                transport.start_worker(0, tiny_cfg)
+        finally:
+            transport.close()
 
 
 # ---------------------------------------------------------------------------
@@ -811,6 +829,10 @@ class TestChaosMatrix:
             worker_timeout=1.0,
             retry=CHAOS_RETRY,
         )
+        # one fixed batch, so the loss series shows training and not the
+        # spread between random batches
+        batch = spec.batch_fn(0)
+        spec = dataclasses.replace(spec, batch_fn=lambda t: batch)
         losses = _LossesHook()
         snaps = _LivenessHook()
         res = run(spec, hooks=[losses, snaps])

@@ -162,10 +162,11 @@ class TestFusedTrajectoryParity:
         W, ring = 4, 8
         adapt = make_worker_adapt(sched.table[:32], [Poisson(4.0)] * W, cdf_support=ring)
         s_u = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt
+            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt, mesh=workers_mesh
         )
         s_f = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt, fuse=True
+            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt, mesh=workers_mesh,
+            fuse=True,
         )
         step_u = jax.jit(make_step(small_cfg, pipe, mode="sharded_async", mesh=workers_mesh))
         step_f = jax.jit(

@@ -70,7 +70,7 @@ class TestShardedBitMatch:
             jax.random.PRNGKey(0), small_cfg, opt, async_ring=ring, adapt=adapt1
         )
         s2 = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt2
+            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt2, mesh=workers_mesh
         )
         step1 = jax.jit(make_async_train_step(small_cfg, opt, alpha_c=0.05, num_workers=W))
         step2 = jax.jit(
@@ -105,10 +105,10 @@ class TestShardedBitMatch:
         )
         adapt = make_worker_adapt(sched.table[:32], [model] * W, cdf_support=ring)
         s1 = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt
+            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt, mesh=workers_mesh
         )
         s2 = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt
+            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt, mesh=workers_mesh
         )
         step1 = jax.jit(
             make_sharded_async_train_step(small_cfg, opt, alpha_c=0.05, mesh=workers_mesh)
@@ -138,7 +138,7 @@ class TestShardedBitMatch:
         )
         adapt = make_worker_adapt(sched.table[:32], [Poisson(3.0)] * W, cdf_support=ring)
         state = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt
+            jax.random.PRNGKey(0), small_cfg, pipe, ring=ring, adapt=adapt, mesh=workers_mesh
         )
         step = jax.jit(make_step(small_cfg, pipe, mode="sharded_async", mesh=workers_mesh))
         batches = lm_batches(small_cfg.vocab_size, 2, 16, seed=0)
@@ -156,7 +156,7 @@ class TestShardedBitMatch:
         adapt = make_worker_adapt(sched.table[:32], [Poisson(3.0)] * W, cdf_support=ring)
         mts = mindthestep(opt, sched, 0.05, m=W, tau_max=31)
         state = init_sharded_async_state(
-            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt
+            jax.random.PRNGKey(0), small_cfg, opt, ring=ring, adapt=adapt, mesh=workers_mesh
         )
         traces = []
         base = make_sharded_async_train_step(small_cfg, opt, alpha_c=0.05, mesh=workers_mesh)
@@ -207,7 +207,7 @@ def _run_sharded(small_cfg, mesh, samplers, n_steps=10, ring=8):
     sched = make_schedule("constant", 0.05, tau_max=31)
     adapt = make_worker_adapt(sched.table[:32], samplers, cdf_support=ring)
     state = init_sharded_async_state(
-        jax.random.PRNGKey(1), small_cfg, opt, ring=ring, adapt=adapt
+        jax.random.PRNGKey(1), small_cfg, opt, ring=ring, adapt=adapt, mesh=mesh
     )
     rng0 = state.rng
     step = jax.jit(make_sharded_async_train_step(small_cfg, opt, alpha_c=0.05, mesh=mesh))

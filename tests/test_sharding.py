@@ -31,6 +31,18 @@ MESH = FakeMesh((16, 16), ("data", "model"))
 MESH3 = FakeMesh((2, 16, 16), ("pod", "data", "model"))
 
 
+@pytest.mark.parametrize("build", ["make_workers_mesh", "make_small_mesh"])
+def test_mesh_axes_are_auto(build):
+    """The sharding constraints and the workers shard_map need Auto axes;
+    jax.make_mesh defaults to Explicit ones."""
+    from jax.sharding import AxisType
+
+    from repro.launch import mesh as M
+
+    mesh = M.make_workers_mesh() if build == "make_workers_mesh" else M.make_small_mesh(1, 1)
+    assert mesh.axis_types == (AxisType.Auto,) * len(mesh.axis_names)
+
+
 class TestParamRules:
     def test_attention_heads_over_model(self):
         spec = param_spec_for("stack/pos0/attn/wq", (2, 4608, 32, 128), MESH)
