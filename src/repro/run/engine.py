@@ -25,6 +25,12 @@ retrace beyond the first compile is an online-adaptation regression (tables
 must stay step inputs), surfaced by :class:`~repro.run.hooks.BenchHook` as a
 gated bench row.
 
+The engine writes host spans into the profiler's trace
+(``jax.profiler.TraceAnnotation``; next to free with no profiler running):
+``engine.tick`` around the dispatch of each tick, ``engine.trace`` around
+each (re)trace of the step, and ``engine.refresh`` around each refresh, whose
+phases are spanned in :mod:`repro.training.adapt`.
+
 :class:`PrebuiltEngine` adapts a hand-built ``(step_fn, state)`` pair to the
 same protocol — it is how the deprecated ``train_loop`` shim rides the
 orchestrator without behavior change.
@@ -36,6 +42,7 @@ import dataclasses
 from typing import Any, Callable, Protocol, runtime_checkable
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.run.spec import RunSpec
 
@@ -136,7 +143,8 @@ class _EngineBase:
     def _jit(self, base: Callable) -> Callable:
         def counting(state, batch):
             self._traces.append(1)  # runs only when jax (re)traces
-            return base(state, batch)
+            with TraceAnnotation("engine.trace"):
+                return base(state, batch)
 
         if self._donate_state and self.spec.fuse:
             # Fused layouts rewrite params / rings / flat optimizer state
@@ -186,7 +194,8 @@ class _EngineBase:
     def tick(self, state, batch):
         if self._tick is None:
             self._tick = self._jit(self._make_step())
-        return self._tick(state, batch)
+        with TraceAnnotation("engine.tick"):
+            return self._tick(state, batch)
 
     def require_refreshable(self, state) -> None:
         """Fail fast (the orchestrator calls this before the first tick):
@@ -204,15 +213,16 @@ class _EngineBase:
             worker_host_refresh,
         )
 
-        self.require_refreshable(state)
-        adapt = state.adapt
-        refresher = _refresher_of(self.pipeline)
-        kwargs = dict(self.spec.refresh_kwargs or {})
-        if isinstance(adapt, WorkerAdaptState):
-            new_adapt = worker_host_refresh(adapt, refresher, mesh=self.mesh, **kwargs)
-        else:
-            new_adapt = host_refresh(adapt, refresher, **kwargs)
-        return dataclasses.replace(state, adapt=new_adapt)
+        with TraceAnnotation("engine.refresh"):
+            self.require_refreshable(state)
+            adapt = state.adapt
+            refresher = _refresher_of(self.pipeline)
+            kwargs = dict(self.spec.refresh_kwargs or {})
+            if isinstance(adapt, WorkerAdaptState):
+                new_adapt = worker_host_refresh(adapt, refresher, mesh=self.mesh, **kwargs)
+            else:
+                new_adapt = host_refresh(adapt, refresher, **kwargs)
+            return dataclasses.replace(state, adapt=new_adapt)
 
     # -- lifecycle defaults (Engine protocol): compiled engines hold no live
     # machinery, so success-path finish is identity, failure-path abort and

@@ -13,6 +13,9 @@ tiny and mode-blind:
     state = engine.finish(state)              # success path: engines drain
     hooks.on_end                              # (failure path: engine.abort())
 
+The loop writes host spans into the profiler's trace: ``run.input`` around
+each ``next(batches)`` and ``run.hooks`` around each pass over the hooks.
+
 Engine modes, fusion, sharding, and the online-adaptation boundary live in
 :mod:`repro.run.engine`; logging/bench/eval/checkpointing live in
 :mod:`repro.run.hooks`.  Resume is first-class: ``resume_from=directory``
@@ -25,6 +28,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Any, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from repro.run.engine import Engine, make_engine
 from repro.run.hooks import Hook
@@ -114,16 +119,19 @@ def run(
         hook.on_start(ctx)
     try:
         for i in range(start_step, spec.num_steps):
-            batch = next(batches)
+            with TraceAnnotation("run.input"):
+                batch = next(batches)
             state, metrics = engine.tick(state, batch)
             ctx.state, ctx.metrics, ctx.step = state, metrics, i + 1
             if spec.refresh_every and (i + 1) % spec.refresh_every == 0:
                 state = engine.refresh(state)
                 ctx.state = state
+                with TraceAnnotation("run.hooks"):
+                    for hook in hooks:
+                        hook.on_refresh(ctx)
+            with TraceAnnotation("run.hooks"):
                 for hook in hooks:
-                    hook.on_refresh(ctx)
-            for hook in hooks:
-                hook.on_tick(ctx)
+                    hook.on_tick(ctx)
     except BaseException:
         # The lifecycle's failure path: engines running live machinery
         # (worker threads/processes) tear it down without draining; a live

@@ -28,6 +28,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.async_engine.delayed import staleness_cdf
 
@@ -264,6 +265,11 @@ def record_worker_taus(hist: jnp.ndarray, taus: jnp.ndarray) -> jnp.ndarray:
 
 # ---------------------------------------------------------------------------
 # Host-side refresh boundary
+#
+# Both refreshes run in three phases, each a host span in the profiler's
+# trace: ``refresh.drain`` (the histogram to the host), ``refresh.refit``
+# (alpha(tau) refit on the host) and ``refresh.swap`` (the new same-shape
+# device arrays).
 # ---------------------------------------------------------------------------
 
 def host_refresh(
@@ -301,25 +307,28 @@ def host_refresh(
     fit anyway.
     """
     assert mts.estimator is not None, "host_refresh needs a MindTheStep with an estimator"
-    counts = np.asarray(jax.device_get(adapt.hist))
-    new_cdf = adapt.tau_cdf
-    if refresh_cdf:
-        # fit() is a pure read (idempotent): build the sampler swap before
-        # refresh() applies the once-per-boundary forgetting.  observe first
-        # so the swap sees this boundary's histogram.
-        mts.estimator.observe_counts(counts)
-        counts = None  # consumed
-        model = mts.estimator.fit(family)
-        new_cdf = staleness_cdf(model.pmf_table(adapt.tau_cdf.shape[0] - 1))
-    table = _refit_alpha_table(
-        counts, mts, strategy=strategy, family=family, K=K,
-        normalize=normalize, logger=logger, n_bins=adapt.alpha_table.shape[0],
-    )
-    return AdaptState(
-        alpha_table=table,
-        tau_cdf=new_cdf,
-        hist=jnp.zeros_like(adapt.hist),
-    )
+    with TraceAnnotation("refresh.drain"):
+        counts = np.asarray(jax.device_get(adapt.hist))
+    with TraceAnnotation("refresh.refit"):
+        new_cdf = adapt.tau_cdf
+        if refresh_cdf:
+            # fit() is a pure read (idempotent): build the sampler swap before
+            # refresh() applies the once-per-boundary forgetting.  observe first
+            # so the swap sees this boundary's histogram.
+            mts.estimator.observe_counts(counts)
+            counts = None  # consumed
+            model = mts.estimator.fit(family)
+            new_cdf = staleness_cdf(model.pmf_table(adapt.tau_cdf.shape[0] - 1))
+        table = _refit_alpha_table(
+            counts, mts, strategy=strategy, family=family, K=K,
+            normalize=normalize, logger=logger, n_bins=adapt.alpha_table.shape[0],
+        )
+    with TraceAnnotation("refresh.swap"):
+        return AdaptState(
+            alpha_table=jnp.asarray(table),
+            tau_cdf=new_cdf,
+            hist=jnp.zeros_like(adapt.hist),
+        )
 
 
 def _refit_alpha_table(
@@ -332,10 +341,10 @@ def _refit_alpha_table(
     normalize: bool,
     logger: Any,
     n_bins: int,
-) -> jnp.ndarray:
+) -> np.ndarray:
     """Shared refresh-boundary core: observe drained ``counts`` (unless the
     caller already fed them), refit/rebuild the schedule, return the new f32
-    table truncated to ``n_bins``."""
+    host table truncated to ``n_bins``."""
     from repro.core.step_size import STRATEGIES
 
     assert mts.estimator is not None, "host_refresh needs a MindTheStep with an estimator"
@@ -367,7 +376,7 @@ def _refit_alpha_table(
         f"refreshed schedule support {len(table) - 1} < adapt tau_max {n_bins - 1}; "
         "construct the estimator with tau_max >= adapt.tau_max"
     )
-    return jnp.asarray(table[:n_bins], jnp.float32)
+    return np.asarray(table[:n_bins], np.float32)
 
 
 def merge_worker_hist(adapt: WorkerAdaptState, mesh=None, axis_name: str = "workers"):
@@ -413,15 +422,20 @@ def worker_host_refresh(
     reset histogram keep the placement of the leaves they replace, so the
     compiled sharded step sees the same input types and does not retrace.
     """
-    counts = np.asarray(jax.device_get(merge_worker_hist(adapt, mesh)))
-    table = _refit_alpha_table(
-        counts, mts, strategy=strategy, family=family, K=K,
-        normalize=normalize, logger=logger, n_bins=adapt.alpha_table.shape[0],
-    )
-    return WorkerAdaptState(
-        alpha_table=jax.device_put(table, adapt.alpha_table.sharding),
-        tau_cdf=adapt.tau_cdf,
-        tau_trace=adapt.tau_trace,
-        use_trace=adapt.use_trace,
-        hist=jax.device_put(jnp.zeros(adapt.hist.shape, adapt.hist.dtype), adapt.hist.sharding),
-    )
+    with TraceAnnotation("refresh.drain"):
+        counts = np.asarray(jax.device_get(merge_worker_hist(adapt, mesh)))
+    with TraceAnnotation("refresh.refit"):
+        table = _refit_alpha_table(
+            counts, mts, strategy=strategy, family=family, K=K,
+            normalize=normalize, logger=logger, n_bins=adapt.alpha_table.shape[0],
+        )
+    with TraceAnnotation("refresh.swap"):
+        return WorkerAdaptState(
+            alpha_table=jax.device_put(table, adapt.alpha_table.sharding),
+            tau_cdf=adapt.tau_cdf,
+            tau_trace=adapt.tau_trace,
+            use_trace=adapt.use_trace,
+            hist=jax.device_put(
+                jnp.zeros(adapt.hist.shape, adapt.hist.dtype), adapt.hist.sharding
+            ),
+        )

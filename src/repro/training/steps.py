@@ -38,6 +38,14 @@ combine → unpack round-trip disappears) and the whole async tick is one
 ``flat_tick_step`` launch.  The trajectory stays bit-identical (f32) to the
 link-by-link execution.  Unfuseable chains fall back with a single warning.
 
+Named scopes mark the parts of every mode's step in the compiled program's
+op metadata, and so in the profiler's device trace: ``param_view`` (the
+leaf-wise view of flat-native params; its transpose packs the gradient),
+``forward`` (the loss; the backward pass shows as
+``transpose(jvp(forward))``), ``staleness`` (tau draws, alpha(tau) lookup,
+drop mask, histogram) and ``update`` (ring push and combine, optimizer
+apply).
+
 ``make_serve_step`` — one decode step against a KV cache (inference shapes
 ``decode_32k`` / ``long_500k``).
 
@@ -358,13 +366,17 @@ def make_step(
             )
 
             def lf_flat(pf):
-                return M.loss_fn(T.flat_view(pf, template), batch, cfg)
+                with jax.named_scope("param_view"):
+                    params = T.flat_view(pf, template)
+                with jax.named_scope("forward"):
+                    return M.loss_fn(params, batch, cfg)
 
             (loss, metrics), g_flat = jax.value_and_grad(lf_flat, has_aux=True)(params)
             return loss, metrics, g_flat
 
         def lf(p):
-            return M.loss_fn(p, batch, cfg)
+            with jax.named_scope("forward"):
+                return M.loss_fn(p, batch, cfg)
 
         (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
         return loss, metrics, _constrain_grads(grads, cfg)
@@ -388,7 +400,8 @@ def make_step(
         def train_step(state: TrainState, batch) -> tuple[TrainState, dict]:
             loss, metrics, grads = loss_and_grads(state.params, batch)
             ctx = T.StepContext(adapt=state.adapt, rng=state.rng)
-            new_params, new_opt = apply_fn(grads, state.opt_state, state.params, ctx)
+            with jax.named_scope("update"):
+                new_params, new_opt = apply_fn(grads, state.opt_state, state.params, ctx)
             new_state = TrainState(
                 params=new_params, opt_state=new_opt, step=state.step + 1,
                 rng=state.rng, delayed=state.delayed, adapt=state.adapt,
@@ -410,45 +423,47 @@ def make_step(
             )
             _check_ring_layout(state.delayed.ring)
             loss, metrics, grads = loss_and_grads(state.params, batch)
-            rng, sub = jax.random.split(state.rng)
-            taus = sample_taus(sub, state.adapt.tau_cdf, W)
-            alpha = alpha_lookup(state.adapt, taus)
-            weights = alpha / jnp.float32(alpha_c * W)
-            keep = _drop_mask(transform, taus)
-            if keep is not None:
-                weights = weights * keep
-            adapt = record_taus(state.adapt, taus)
+            with jax.named_scope("staleness"):
+                rng, sub = jax.random.split(state.rng)
+                taus = sample_taus(sub, state.adapt.tau_cdf, W)
+                alpha = alpha_lookup(state.adapt, taus)
+                weights = alpha / jnp.float32(alpha_c * W)
+                keep = _drop_mask(transform, taus)
+                if keep is not None:
+                    weights = weights * keep
+                adapt = record_taus(state.adapt, taus)
             ctx = T.StepContext(
                 taus=taus, adapt=adapt, rng=rng, staleness_applied=True
             )
-            if fused_flat:
-                # ONE-LAUNCH TICK: ring push + alpha-weighted combine +
-                # scalars + body + apply, all flat-resident (flat_tick_step;
-                # 1 launch on TPU, 2 with clip).  Gradients are born flat
-                # under flat-native params; non-f32 storage packs once here.
-                from repro.optim.fuse import flat_tick_step
+            with jax.named_scope("update"):
+                if fused_flat:
+                    # ONE-LAUNCH TICK: ring push + alpha-weighted combine +
+                    # scalars + body + apply, all flat-resident (flat_tick_step;
+                    # 1 launch on TPU, 2 with clip).  Gradients are born flat
+                    # under flat-native params; non-f32 storage packs once here.
+                    from repro.optim.fuse import flat_tick_step
 
-                opt = state.opt_state
-                assert isinstance(opt, dict) and set(opt) == {"p", "bufs"}, (
-                    "fused async step got a non-fused opt state — initialize "
-                    "it with init_train_state(..., fuse=True)"
-                )
-                flat_params = isinstance(state.params, jax.Array)
-                if opt["p"] is not None:
-                    p_flat = opt["p"]
+                    opt = state.opt_state
+                    assert isinstance(opt, dict) and set(opt) == {"p", "bufs"}, (
+                        "fused async step got a non-fused opt state — initialize "
+                        "it with init_train_state(..., fuse=True)"
+                    )
+                    flat_params = isinstance(state.params, jax.Array)
+                    if opt["p"] is not None:
+                        p_flat = opt["p"]
+                    else:
+                        p_flat = state.params if flat_params else T.pack_flat(state.params)
+                    p_new, bufs, new_ring, live = flat_tick_step(
+                        plan, state.delayed, _flat_grads(grads), taus, weights,
+                        opt["bufs"], p_flat, ctx,
+                    )
+                    new_opt = {"p": p_new if opt["p"] is not None else None, "bufs": bufs}
+                    new_params = p_new if flat_params else T.unpack_flat(p_new, state.params)
                 else:
-                    p_flat = state.params if flat_params else T.pack_flat(state.params)
-                p_new, bufs, new_ring, live = flat_tick_step(
-                    plan, state.delayed, _flat_grads(grads), taus, weights,
-                    opt["bufs"], p_flat, ctx,
-                )
-                new_opt = {"p": p_new if opt["p"] is not None else None, "bufs": bufs}
-                new_params = p_new if flat_params else T.unpack_flat(p_new, state.params)
-            else:
-                g_eff, live, new_ring = delayed_combine(
-                    state.delayed, grads, taus, weights
-                )
-                new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
+                    g_eff, live, new_ring = delayed_combine(
+                        state.delayed, grads, taus, weights
+                    )
+                    new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
             new_state = TrainState(
                 params=new_params, opt_state=new_opt, step=state.step + 1,
                 rng=rng, delayed=new_ring, adapt=adapt,
@@ -485,23 +500,26 @@ def make_step(
             # the fused apply all run over one packed buffer per shard (the
             # pack is a no-op for born-flat flat-native gradients)
             grads = _flat_grads(grads)
-        rng, sub = jax.random.split(state.rng)
-        u = jax.random.uniform(sub, (W,))
+        with jax.named_scope("staleness"):
+            rng, sub = jax.random.split(state.rng)
+            u = jax.random.uniform(sub, (W,))
 
         ring_specs = jax.tree.map(lambda _: P(axis_name), ring.ring)
         grad_specs = jax.tree.map(lambda _: P(), grads)
 
         def tick(ring_leaves, step, grads, u, cdf, trace, flags, hist, alpha_table):
-            taus = sample_worker_taus(u, cdf, trace, flags, step)
-            alpha = alpha_table[jnp.clip(taus, 0, alpha_table.shape[0] - 1)]
-            weights = alpha / jnp.float32(alpha_c * W)
-            keep = _drop_mask(transform, taus)
-            if keep is not None:
-                weights = weights * keep
-            g_eff, live, new_ring = worker_ring_combine(
-                ring_leaves, step, grads, taus, weights, axis_name=axis_name
-            )
-            new_hist = record_worker_taus(hist, taus)
+            with jax.named_scope("staleness"):
+                taus = sample_worker_taus(u, cdf, trace, flags, step)
+                alpha = alpha_table[jnp.clip(taus, 0, alpha_table.shape[0] - 1)]
+                weights = alpha / jnp.float32(alpha_c * W)
+                keep = _drop_mask(transform, taus)
+                if keep is not None:
+                    weights = weights * keep
+                new_hist = record_worker_taus(hist, taus)
+            with jax.named_scope("update"):
+                g_eff, live, new_ring = worker_ring_combine(
+                    ring_leaves, step, grads, taus, weights, axis_name=axis_name
+                )
             stats = jax.lax.psum(
                 jnp.stack(
                     [jnp.sum(taus.astype(jnp.float32)), jnp.sum(alpha), jnp.sum(live)]
@@ -532,25 +550,26 @@ def make_step(
             use_trace=adapt.use_trace,
             hist=new_hist,
         )
-        if fused_flat:
-            # XLA cannot partition a Pallas kernel: every device runs the
-            # fused apply on its own replica of params, g_eff and opt state.
-            # The fused body reads no ctx data once staleness is applied.
-            new_params, new_opt = jax.shard_map(
-                lambda g, opt, p: apply_fn(
-                    g, opt, p,
-                    T.StepContext(axis_name=axis_name, staleness_applied=True),
-                ),
-                mesh=mesh,
-                in_specs=(P(), P(), P()),
-                out_specs=(P(), P()),
-                check_vma=False,
-            )(g_eff, state.opt_state, state.params)
-        else:
-            ctx = T.StepContext(
-                adapt=new_adapt, rng=rng, axis_name=axis_name, staleness_applied=True
-            )
-            new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
+        with jax.named_scope("update"):
+            if fused_flat:
+                # XLA cannot partition a Pallas kernel: every device runs the
+                # fused apply on its own replica of params, g_eff and opt state.
+                # The fused body reads no ctx data once staleness is applied.
+                new_params, new_opt = jax.shard_map(
+                    lambda g, opt, p: apply_fn(
+                        g, opt, p,
+                        T.StepContext(axis_name=axis_name, staleness_applied=True),
+                    ),
+                    mesh=mesh,
+                    in_specs=(P(), P(), P()),
+                    out_specs=(P(), P()),
+                    check_vma=False,
+                )(g_eff, state.opt_state, state.params)
+            else:
+                ctx = T.StepContext(
+                    adapt=new_adapt, rng=rng, axis_name=axis_name, staleness_applied=True
+                )
+                new_params, new_opt = apply_fn(g_eff, state.opt_state, state.params, ctx)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1,
             rng=rng, delayed=WorkerRing(ring=new_ring, step=ring.step + 1),

@@ -133,3 +133,69 @@ def test_chain_kernel_fits_one_chip(kind, one_chip):
         _vec(one_chip), _vec(one_chip), _bufs(kind, one_chip), _scalars(kind, one_chip)
     ).compile()
     _check_fits(compiled)
+
+
+# -- names the benchmark's trace reduction reads ----------------------------
+
+BODY_SCOPES = (
+    "jvp(param_view)", "transpose(jvp(param_view))", "jvp(forward)", "transpose(jvp(forward))",
+)
+STEP_SCOPES = {"async": BODY_SCOPES + ("staleness", "update"), "sync": BODY_SCOPES + ("update",)}
+
+
+@pytest.mark.parametrize("mode", sorted(STEP_SCOPES))
+def test_step_scopes_survive_compilation(mode, one_chip):
+    """The step body's named scopes reach the compiled program's ``op_name``
+    metadata, which the device trace carries.  At tiny widths; the step
+    takes the CPU branch of ``use_pallas``, which holds the same scopes."""
+    import re
+
+    from repro.configs import get_config, reduced
+    from repro.data import make_batch_for
+    from repro.launch.train import mindthestep_pipeline
+    from repro.training.steps import init_train_state, make_step
+
+    cfg = reduced(get_config("stablelm-1.6b"), d_model=64)
+    W = 4 if mode == "async" else 1
+    pipeline, adapt = mindthestep_pipeline(0.01, W, K, momentum=0.9, staleness=mode == "async")
+    step = make_step(cfg, pipeline, mode=mode, num_workers=W, fuse=True)
+    state = jax.eval_shape(
+        lambda key: init_train_state(
+            key, cfg, pipeline, async_ring=K if mode == "async" else 0, adapt=adapt,
+            fuse=True, ring_dtype=jnp.bfloat16,
+        ),
+        jax.random.PRNGKey(0),
+    )
+    batch = jax.eval_shape(lambda: make_batch_for(cfg, batch=2, seq=16, seed=0))
+    shapes = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), (state, batch))
+    text = jax.jit(step, donate_argnums=(0,)).lower(*shapes).compile().as_text()
+    components = {
+        c for path in re.findall(r'op_name="([^"]*)"', text)
+        for p in path.split(";") for c in p.split("/")
+    }
+    assert set(STEP_SCOPES[mode]) <= components, set(STEP_SCOPES[mode]) - components
+
+
+def test_update_launch_keeps_its_name(one_chip):
+    """A ``fused_tick_call`` nested in a jitted step, as the step calls it,
+    compiles to a Pallas launch whose instruction name the benchmark's trace
+    reduction finds (``bench/trace.py::UPDATE_KERNEL``)."""
+    import re
+
+    from bench.trace import UPDATE_KERNEL
+
+    n = 3 * 8192
+
+    def step(p, g, bufs, scalars, ring, push, w_slot):
+        with jax.named_scope("update"):
+            return fused_tick_call("momentum", p * 1.0, g, bufs, scalars, ring, push, w_slot)
+
+    vec = _sds((n,), jnp.float32, one_chip)
+    kvec = _sds((K, 1), jnp.float32, one_chip)
+    text = jax.jit(step).lower(
+        vec, vec, (vec,), _scalars("momentum", one_chip),
+        _sds((K, n), jnp.bfloat16, one_chip), kvec, kvec,
+    ).compile().as_text()
+    launches = re.findall(r"^\s*(?:ROOT )?(%\S+) = .*tpu_custom_call", text, re.M)
+    assert launches
+    assert all(UPDATE_KERNEL.search(name) for name in launches), launches
