@@ -237,11 +237,11 @@ def kernel_parity(n: int, *, seed: int, interpret: bool = False) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels.adaptive_update.fused import _TILE, fused_tick_flat
+    from repro.kernels.adaptive_update.fused import HBM_TILE, fused_tick_flat
     from repro.kernels.adaptive_update.ref import fused_tick_ref
 
     size = -(-n // PARITY_CHUNKS)
-    size = -(-size // _TILE) * _TILE  # chunk bounds on block bounds; last one ragged
+    size = -(-size // HBM_TILE) * HBM_TILE  # chunk bounds on HBM tiles; last one ragged
     bounds = [(a, min(a + size, n)) for a in range(0, n, size)]
     key = jax.random.PRNGKey(seed)
 

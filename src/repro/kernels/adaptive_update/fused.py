@@ -8,20 +8,33 @@ links enter as SCALAR factors (``f_stale``/``f_keep``/``f_clip``), so the
 "± clip" variants reuse the same kernels: the norm reduction happens outside
 (it is a second data pass by nature) and only its scalar result is fused in.
 
-Every ``_TILE``-element block of ``p``/``g``/state is read once and written
-once — the whole server update is a single HBM pass no matter how many links
-the chain has, vs one read+write pass PER LINK for the link-by-link
-``tree.map`` execution.  Scalars ride as (1, 1) SMEM-friendly tiles exactly
-like the original ``adaptive_update`` kernel, so one compiled kernel serves
-every staleness value / clip factor / bias-correction step.
+Every block of ``p``/``g``/state is read once and written once — the whole
+server update is a single HBM pass no matter how many links the chain has, vs
+one read+write pass PER LINK for the link-by-link ``tree.map`` execution.
+Scalars ride as (1, 1) SMEM-friendly tiles exactly like the original
+``adaptive_update`` kernel, so one compiled kernel serves every staleness
+value / clip factor / bias-correction step.
 
 The kernels work on the flat buffers as they are stored: 1-D ``(N,)``
-params / gradient / state and the ``(K, N)`` ring, blocked ``_TILE``
-elements at a time over a ``cdiv(N, _TILE)`` grid, so a ragged tail is the
+params / gradient / state and the ``(K, N)`` ring, blocked ``block``
+elements at a time over a ``cdiv(N, block)`` grid, so a ragged tail is the
 partial last block (masked by Pallas) and nothing is padded, reshaped or
 sliced around the launch.  Params, ring and optimizer state are aliased
 input -> output (``input_output_aliases``): under a donating jit the kernel
 updates them in place, and the step holds one copy of each.
+
+Block size.  A grid step costs a fixed ~0.3 us on a v5e besides its bytes,
+so at 8,192-element blocks about half of a launch over 600M parameters was
+paid per step, not per byte.  Each launch therefore streams the widest
+block that a fixed VMEM budget holds of its own operands, double-buffered:
+``_VMEM_BUDGET`` over the bytes one element costs in every in and out
+operand (K ring elements per column), in whole chunks, capped at ``N``
+rounded up to an HBM tile (:func:`block_elems`).  So the family, the state
+count, the ring's K and dtype each give their launch its own block.  The
+body then runs over the block ``_CHUNK`` elements at a time
+(:func:`_in_chunks`): a body written over a whole wide block keeps every
+intermediate at block width in VMEM, which held the tick at ~65% of HBM
+bandwidth.  Budget and chunk were chosen by a sweep on the chip (PERF.md).
 
 Scalar factors are applied sequentially in link order (never pre-multiplied):
 float multiplication is not associative, and bit-equality with the unfused
@@ -31,12 +44,13 @@ pipeline is the contract (`f = 1.0` for an absent link is bitwise exact).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.adaptive_update.kernel import BLOCK_ROWS, LANES
 from repro.kernels.adaptive_update.ref import fused_chain_ref, fused_tick_ref
 
 __all__ = [
@@ -48,8 +62,6 @@ __all__ = [
     "fused_combine_flat",
     "SCALAR_ORDER",
 ]
-
-_TILE = BLOCK_ROWS * LANES
 
 # Scalar bundle keys per family, in kernel-operand order.
 SCALAR_ORDER = {
@@ -133,15 +145,52 @@ _KERNELS = {
 
 
 _SCALAR = pl.BlockSpec((1, 1), lambda i: (0, 0))
-_VEC = pl.BlockSpec((_TILE,), lambda i: (i,))
+
+HBM_TILE = 1024  # elements of one HBM tile of a flat f32 buffer
+_CHUNK = 8192  # elements the body computes at a time: its values stay small
+_VMEM_BUDGET = 9 * 2**20  # bytes of double-buffered operand blocks a launch streams
+_VMEM_LIMIT = _VMEM_BUDGET + 4 * 2**20  # and room for one chunk's values
 
 
-def _grid(n: int) -> tuple[int]:
-    return (pl.cdiv(n, _TILE),)
+def block_elems(n: int, operands) -> int:
+    """Elements a grid step streams: as many as the VMEM budget holds of the
+    launch's in and out ``operands`` (a ``(K, N)`` ring costs K elements a
+    column), double-buffered, in whole chunks, and never wider than ``n``
+    rounded up to an HBM tile."""
+    per_elem = 2 * sum(x.dtype.itemsize * (x.shape[0] if x.ndim == 2 else 1) for x in operands)
+    block = _VMEM_BUDGET // per_elem // _CHUNK * _CHUNK
+    return max(HBM_TILE, min(block, pl.cdiv(n, HBM_TILE) * HBM_TILE))
 
 
-def _ring_block(K: int) -> pl.BlockSpec:
-    return pl.BlockSpec((K, _TILE), lambda i: (0, i))
+def _in_chunks(kernel, n_whole: int):
+    """``kernel`` run over the chunks of its block: the first ``n_whole``
+    refs (scalars, ``(K, 1)`` weights) whole, the rest sliced by column."""
+
+    def body(*refs):
+        whole, blocked = refs[:n_whole], refs[n_whole:]
+        block = blocked[0].shape[-1]
+        chunk = math.gcd(block, _CHUNK)
+
+        def step(c, carry):
+            cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            kernel(*whole, *(r.at[cols] if r.ndim == 1 else r.at[:, cols] for r in blocked))
+            return carry
+
+        jax.lax.fori_loop(0, block // chunk, step, 0)
+
+    return body
+
+
+def _blocked(n: int, operands):
+    """Grid, vector and ring block specs and compiler params of one launch."""
+    block = block_elems(n, operands)
+    vec = pl.BlockSpec((block,), lambda i: (i,))
+
+    def ring(K):
+        return pl.BlockSpec((K, block), lambda i: (0, i))
+
+    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+    return (pl.cdiv(n, block),), vec, ring, params
 
 
 def _scalar_tiles(kind: str, scalars) -> list:
@@ -165,14 +214,17 @@ def fused_chain_call(kind: str, p, g, bufs, scalars, *, interpret: bool = False)
     assert len(bufs) == n_bufs, f"{kind} expects {n_bufs} state buffers, got {len(bufs)}"
     svals = _scalar_tiles(kind, scalars)
     S = len(svals)
+    out_shape = [_shape_of(p)] + [_shape_of(b) for b in bufs]
+    grid, vec, _, params = _blocked(p.shape[0], [p, g, *bufs, *out_shape])
     out = pl.pallas_call(
-        kernel,
-        grid=_grid(p.shape[0]),
-        in_specs=[_SCALAR] * S + [_VEC] * (2 + n_bufs),
-        out_specs=[_VEC] * (1 + n_bufs),
-        out_shape=[_shape_of(p)] + [_shape_of(b) for b in bufs],
+        _in_chunks(kernel, S),
+        grid=grid,
+        in_specs=[_SCALAR] * S + [vec] * (2 + n_bufs),
+        out_specs=[vec] * (1 + n_bufs),
+        out_shape=out_shape,
         # p -> p_new, bufs[j] -> new_bufs[j]  (operand g at S + 1 is read-only)
         input_output_aliases={S: 0, **{S + 2 + j: 1 + j for j in range(n_bufs)}},
+        compiler_params=params,
         interpret=interpret,
     )(*svals, p, g, *bufs)
     return out[0], tuple(out[1:])
@@ -217,7 +269,7 @@ def fused_chain_flat(
 #
 # The tick kernels take the whole flat-resident delayed ring as its (K, N)
 # operand, blocked over the SAME grid as p/g/state: each grid step owns a
-# (K, _TILE) ring block, pushes the fresh gradient into
+# (K, block) ring block, pushes the fresh gradient into
 # slot t%K via a one-hot select, contracts the K slots against the slot-folded
 # combine weights, and feeds the result straight into the chain body — params,
 # ring slot and optimizer state are all written in the same pass, so the whole
@@ -234,8 +286,8 @@ def fused_chain_flat(
 
 def _tick_combine(push_ref, wsl_ref, g_ref, r_ref, r_out_ref):
     """Push the fresh gradient into the ring block and combine the K slots."""
-    r = r_ref[...]  # (K, _TILE)
-    g = g_ref[...].astype(r.dtype)  # (_TILE,): the push stores the ring-dtype cast
+    r = r_ref[...]  # (K, block)
+    g = g_ref[...].astype(r.dtype)  # (block,): the push stores the ring-dtype cast
     r_new = jnp.where(push_ref[...] > 0, g[None, :], r)  # push: (K, 1) one-hot
     r_out_ref[...] = r_new
     return jnp.sum(wsl_ref[...] * r_new.astype(jnp.float32), axis=0)  # (K, 1) weights
@@ -315,16 +367,19 @@ def fused_tick_call(kind: str, p, g, bufs, scalars, ring, push, w_slot, *, inter
     svals = _scalar_tiles(kind, scalars)
     S = len(svals)
     kvec = pl.BlockSpec((K, 1), lambda i: (0, 0))
+    out_shape = [_shape_of(p), _shape_of(ring)] + [_shape_of(b) for b in bufs]
+    grid, vec, ring_block, params = _blocked(p.shape[0], [p, g, ring, *bufs, *out_shape])
     out = pl.pallas_call(
-        kernel,
-        grid=_grid(p.shape[0]),
-        in_specs=[_SCALAR] * S + [kvec, kvec, _VEC, _VEC, _ring_block(K)] + [_VEC] * n_bufs,
-        out_specs=[_VEC, _ring_block(K)] + [_VEC] * n_bufs,
-        out_shape=[_shape_of(p), _shape_of(ring)] + [_shape_of(b) for b in bufs],
+        _in_chunks(kernel, S + 2),
+        grid=grid,
+        in_specs=[_SCALAR] * S + [kvec, kvec, vec, vec, ring_block(K)] + [vec] * n_bufs,
+        out_specs=[vec, ring_block(K)] + [vec] * n_bufs,
+        out_shape=out_shape,
         # p -> p_new, ring -> new_ring, bufs[j] -> new_bufs[j]
         input_output_aliases={
             S + 2: 0, S + 4: 1, **{S + 5 + j: 2 + j for j in range(n_bufs)}
         },
+        compiler_params=params,
         interpret=interpret,
     )(*svals, push, w_slot, p, g, ring, *bufs)
     return out[0], tuple(out[2:]), out[1]
@@ -343,13 +398,16 @@ def fused_combine_call(g, ring, push, w_slot, *, interpret: bool = False):
     """
     K = ring.shape[0]
     kvec = pl.BlockSpec((K, 1), lambda i: (0, 0))
+    out_shape = [jax.ShapeDtypeStruct(g.shape, jnp.float32), _shape_of(ring)]
+    grid, vec, ring_block, params = _blocked(g.shape[0], [g, ring, *out_shape])
     g_eff, new_ring = pl.pallas_call(
-        _combine_kernel,
-        grid=_grid(g.shape[0]),
-        in_specs=[kvec, kvec, _VEC, _ring_block(K)],
-        out_specs=[_VEC, _ring_block(K)],
-        out_shape=[jax.ShapeDtypeStruct(g.shape, jnp.float32), _shape_of(ring)],
+        _in_chunks(_combine_kernel, 2),
+        grid=grid,
+        in_specs=[kvec, kvec, vec, ring_block(K)],
+        out_specs=[vec, ring_block(K)],
+        out_shape=out_shape,
         input_output_aliases={3: 1},
+        compiler_params=params,
         interpret=interpret,
     )(push, w_slot, g, ring)
     return g_eff, new_ring

@@ -7,10 +7,10 @@ multiplications and additions") is elementwise over every parameter:
     x <- x + v
 
 Unfused, that is 3 full HBM passes (read v, read g + write v, read/write x).
-This kernel fuses scale + momentum + apply into ONE pass: each (8k, 128)
-VMEM tile is read once and written once, hitting the HBM roofline for the
-server step — the TPU-native answer to the paper's "apply must be fast so
-tau_S stays small" requirement.
+This kernel fuses scale + momentum + apply into ONE pass over a padded
+``(R, 128)`` view: each ``(BLOCK_ROWS, 128)`` VMEM tile is read once and
+written once.  The fused-chain launches of :mod:`.fused` work on the flat
+buffers as stored and size their own, much wider blocks.
 
 ``alpha`` arrives as a (1, 1) scalar tile (SMEM-friendly) so the same
 compiled kernel serves every staleness value — the alpha(tau) gather happens

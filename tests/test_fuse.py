@@ -457,6 +457,111 @@ class TestOneLaunchTickKernels:
         )
 
 
+@pytest.mark.pallas
+class TestWideBlockKernels:
+    """Each launch sizes its own block (``fused.block_elems``) and computes it
+    chunk by chunk: parity with the oracles where ``n`` spans two full blocks
+    and a ragged tail, where ``n`` is below one 1,024-element tile, and where
+    one block is not a whole number of chunks (CI kernels leg).  Tolerances
+    as in the cases above."""
+
+    N_BUFS = {"sgd": 0, "momentum": 1, "adam": 2}
+    K = 8
+    TAIL = 300
+
+    def _operands(self, launch, kind, n, ring_dtype):
+        vec = jax.ShapeDtypeStruct((n,), jnp.float32)
+        ring = jax.ShapeDtypeStruct((self.K, n), ring_dtype)
+        nb = self.N_BUFS.get(kind, 0)
+        if launch == "tick":
+            return [vec] * (3 + 2 * nb) + [ring] * 2
+        if launch == "chain":
+            return [vec] * (3 + 2 * nb)
+        return [vec] * 2 + [ring] * 2
+
+    def _scalars(self, kind):
+        s = {"f_stale": 1.3, "f_keep": 1.0, "f_clip": 0.7, "m_scale": -0.05}
+        s.update({"momentum": {"mu": 0.9}, "sgd": {}}.get(kind, {
+            "b1": 0.9, "omb1": 0.1, "b2": 0.999, "omb2": 0.001, "eps": 1e-8,
+            "c1": 10.0, "c2": 1000.0,
+        }))
+        return {k: jnp.float32(v) for k, v in s.items()}
+
+    def _bufs(self, kind, n):
+        if kind == "adam":
+            return {"m": jnp.zeros(n, jnp.float32) + 0.1, "v": jnp.zeros(n, jnp.float32) + 0.2}
+        return jnp.zeros(n, jnp.float32) + 0.3 if kind == "momentum" else ()
+
+    def _run(self, launch, kind, n, monkeypatch):
+        """The launch's Pallas result, the oracle's and the blocks it chose."""
+        from repro.kernels.adaptive_update import fused
+        from repro.kernels.adaptive_update.ref import fused_chain_ref, fused_tick_ref
+
+        blocks = []
+        real = fused.block_elems
+        monkeypatch.setattr(
+            fused, "block_elems", lambda n, ops: blocks.append(real(n, ops)) or blocks[-1]
+        )
+        rng = np.random.default_rng(11)
+        p, g = (jnp.asarray(rng.standard_normal(n), jnp.float32) for _ in range(2))
+        ring = jnp.asarray(rng.standard_normal((self.K, n)), jnp.float32)
+        step, weights = jnp.int32(11), jnp.asarray(rng.uniform(0.1, 1.0, 4), jnp.float32)
+        taus = jnp.asarray([0, 9, 5, 2], jnp.int32)  # worker 1: tau >= K, dead
+        if launch == "combine":
+            ring = ring.astype(jnp.bfloat16)
+            got = fused.fused_combine_flat(
+                g, ring, step, taus, weights, use_pallas=True, interpret=True
+            )
+            want = fused.fused_combine_flat(g, ring, step, taus, weights, use_pallas=False)
+            return got, want, blocks
+        bufs, s = self._bufs(kind, n), self._scalars(kind)
+        if launch == "chain":
+            got = fused.fused_chain_flat(kind, p, g, bufs, s, use_pallas=True, interpret=True)
+            return got, fused_chain_ref(kind, p, g, bufs, s), blocks
+        got = fused.fused_tick_flat(
+            kind, p, g, bufs, s, ring, step, taus, weights, use_pallas=True, interpret=True
+        )
+        return got, fused_tick_ref(kind, p, g, bufs, s, ring, step, taus, weights), blocks
+
+    def _check(self, launch, got, want):
+        tol = 1e-5 if launch == "combine" else 1e-6
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            if x.dtype == jnp.bfloat16:  # the ring: the push stores g's cast
+                np.testing.assert_array_equal(np.asarray(x).view(np.uint16),
+                                              np.asarray(y).view(np.uint16))
+            else:
+                np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize(
+        "launch,kind",
+        [(launch, kind) for launch in ("tick", "chain") for kind in ("sgd", "momentum", "adam")]
+        + [("combine", None)],
+    )
+    def test_two_blocks_and_a_ragged_tail(self, launch, kind, monkeypatch):
+        from repro.kernels.adaptive_update.fused import block_elems
+
+        ring_dtype = jnp.bfloat16 if launch == "combine" else jnp.float32
+        block = block_elems(2**40, self._operands(launch, kind, 2**40, ring_dtype))
+        n = 2 * block + self.TAIL
+        got, want, blocks = self._run(launch, kind, n, monkeypatch)
+        assert blocks == [block], (blocks, block)  # the launch grid is (3,), last block ragged
+        self._check(launch, got, want)
+
+    @pytest.mark.parametrize("launch", ["tick", "chain", "combine"])
+    def test_below_one_tile(self, launch, monkeypatch):
+        got, want, blocks = self._run(launch, "momentum", 700, monkeypatch)
+        assert blocks == [1024], blocks
+        self._check(launch, got, want)
+
+    @pytest.mark.parametrize("launch", ["tick", "chain", "combine"])
+    def test_one_block_in_uneven_chunks(self, launch, monkeypatch):
+        """A small ``n`` is one block of whole HBM tiles, computed in chunks
+        that divide it (10,240 = 5 x 2,048)."""
+        got, want, blocks = self._run(launch, "momentum", 10_000, monkeypatch)
+        assert blocks == [10_240], blocks
+        self._check(launch, got, want)
+
+
 class TestFlatNativeRuntime:
     """Satellites: ring-dtype configurability and fused-tick buffer donation."""
 

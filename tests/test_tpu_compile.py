@@ -5,9 +5,13 @@ accepts: block tiling, VMEM use, and whether a launch fits in HBM.  These
 tests compile each kernel of the fused tick for one chip of a described
 ``v5e:2x2`` topology (no chip attached, nothing runs) at N = 411,078,656,
 the parameter count of stablelm-1.6b at 4 layers that ``chip_smoke.py``
-trains, which is not a multiple of the 8192-element block.  Each compile
+trains, which is not a multiple of any block the launches choose.  Each compile
 must hold a Pallas kernel (``tpu_custom_call``) and, with params, ring and
-optimizer state donated as the engines donate them, fit one chip's HBM.
+optimizer state donated as the engines donate them, fit one chip's HBM,
+and update params, ring and state in place: one launch that aliases them, and
+no copy of a flat buffer or of the ring.  The launches the benchmark's cells
+run are also compiled at stablelm-1.6b's N at 4 layers with its untied head
+(where an f32 ring would not fit one chip), and each must stream wide blocks.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
@@ -17,11 +21,13 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels.adaptive_update import fused
 from repro.kernels.adaptive_update.fused import (
     SCALAR_ORDER,
     fused_chain_call,
@@ -30,6 +36,7 @@ from repro.kernels.adaptive_update.fused import (
 )
 
 N = 411_078_656
+N_CELL = 616_599_552  # the benchmark's stablelm-1.6b-4l
 K = 4
 HBM_BYTES = 15.75e9  # what the v5e compiler lets one program use
 N_BUFS = {"sgd": 0, "momentum": 1, "adam": 2}
@@ -63,12 +70,12 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
 
-def _vec(sharding):
-    return _sds((N,), jnp.float32, sharding)
+def _vec(sharding, n=N):
+    return _sds((n,), jnp.float32, sharding)
 
 
-def _ring(dtype, sharding):
-    return _sds((K, N), dtype, sharding)
+def _ring(dtype, sharding, n=N):
+    return _sds((K, n), dtype, sharding)
 
 
 def _kvec(sharding):
@@ -79,12 +86,20 @@ def _scalars(kind, sharding):
     return {k: _sds((), jnp.float32, sharding) for k in SCALAR_ORDER[kind]}
 
 
-def _bufs(kind, sharding):
-    return tuple(_vec(sharding) for _ in range(N_BUFS[kind]))
+def _bufs(kind, sharding, n=N):
+    return tuple(_vec(sharding, n) for _ in range(N_BUFS[kind]))
 
 
-def _check_fits(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _check_fits(compiled, n_aliased, n=N):
+    """One Pallas launch that aliases ``n_aliased`` operands to its outputs,
+    no copy of an ``n``-element buffer, and the program within HBM."""
+    text = compiled.as_text()
+    launches = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(launches) == 1, launches
+    aliasing = re.search(r"output_to_operand_aliasing=\{(.*?)\}, ", launches[0])
+    assert aliasing and len(re.findall(r"\{\d*\}: \(\d+, \{\}\)", aliasing.group(1))) == n_aliased
+    copies = re.findall(rf"= \w+\[(?:\d+,)*{n}\]\S* copy\(", text)
+    assert not copies, copies
     m = compiled.memory_analysis()
     total = (
         m.argument_size_in_bytes
@@ -109,7 +124,7 @@ def test_tick_kernel_fits_one_chip(kind, ring_dtype, one_chip):
         _scalars(kind, one_chip), _ring(ring_dtype, one_chip),
         _kvec(one_chip), _kvec(one_chip),
     ).compile()
-    _check_fits(compiled)
+    _check_fits(compiled, 2 + N_BUFS[kind])  # p, ring, state
 
 
 @pytest.mark.parametrize("ring_dtype", RING_DTYPES)
@@ -120,7 +135,7 @@ def test_combine_kernel_fits_one_chip(ring_dtype, one_chip):
     compiled = combine.lower(
         _vec(one_chip), _ring(ring_dtype, one_chip), _kvec(one_chip), _kvec(one_chip)
     ).compile()
-    _check_fits(compiled)
+    _check_fits(compiled, 1)  # ring
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -132,7 +147,42 @@ def test_chain_kernel_fits_one_chip(kind, one_chip):
     compiled = chain.lower(
         _vec(one_chip), _vec(one_chip), _bufs(kind, one_chip), _scalars(kind, one_chip)
     ).compile()
-    _check_fits(compiled)
+    _check_fits(compiled, 1 + N_BUFS[kind])  # p, state
+
+
+@pytest.mark.parametrize("launch", ["tick", "chain"])
+def test_cell_launch_fits_one_chip_in_wide_blocks(launch, one_chip, monkeypatch):
+    """The momentum launches the cells run, at the cells' N: the tick with a
+    K = 4 bf16 ring and the sync chain.  Each must fit, update in place, and
+    stream blocks of at least 32,768 elements (at 8,192 a fixed cost per grid
+    step took about half the launch on a v5e)."""
+    blocks = []
+
+    def spy(n, operands):
+        blocks.append(real(n, operands))
+        return blocks[-1]
+
+    real = fused.block_elems
+    monkeypatch.setattr(fused, "block_elems", spy)
+    vec, bufs = _vec(one_chip, N_CELL), _bufs("momentum", one_chip, N_CELL)
+    scalars = _scalars("momentum", one_chip)
+    if launch == "tick":
+        fused_tick_call.clear_cache()
+        compiled = jax.jit(
+            functools.partial(fused_tick_call, "momentum", interpret=False),
+            donate_argnums=(0, 2, 4),
+        ).lower(
+            vec, vec, bufs, scalars, _ring(jnp.bfloat16, one_chip, N_CELL),
+            _kvec(one_chip), _kvec(one_chip),
+        ).compile()
+    else:
+        fused_chain_call.clear_cache()
+        compiled = jax.jit(
+            functools.partial(fused_chain_call, "momentum", interpret=False),
+            donate_argnums=(0, 2),
+        ).lower(vec, vec, bufs, scalars).compile()
+    _check_fits(compiled, 3 if launch == "tick" else 2, n=N_CELL)
+    assert len(blocks) == 1 and blocks[0] >= 32_768, blocks
 
 
 # -- names the benchmark's trace reduction reads ----------------------------
