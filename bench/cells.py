@@ -6,19 +6,24 @@ Each lives in a file of its own under this directory:
 * ``configs/<config>.json``: the model as it is run.  Every key that is a
   field of the program's ``ModelConfig`` is applied to the program's
   registered architecture (``arch``) and must read back unchanged; the other
-  keys (``source``, ``reduced``, ``assumed``, ``deployment``, ``count``) are
-  the configuration's record.
+  keys (``source``, ``reduced``, ``assumed``, ``deployment``) are the
+  configuration's record, and ``model`` and ``count`` name its modules:
+  ``models/<model>.py`` (weight shapes and plain loss, for the weights and
+  the reference) and ``counts/<count>.py`` (FLOPs a tick).
 * ``traffic/<traffic>.json``: the job: engine, workers, ring, optimizer
   body, batch, positions per row, refresh cadence, pool of batches.
 * ``limits/<cell>.json``: the limit of each number that decides ``correct``.
+* ``metrics/<metric>.py``: the reader of one per-layer metric.
 
-Adding a cell, a configuration or a traffic mix is adding files and an
-entry; nothing here names one.
+Adding a cell, a configuration, a model, a traffic mix or a metric is adding
+files and an entry; nothing here names one.  :func:`module` loads each
+module file by its name.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 from pathlib import Path
 
@@ -34,10 +39,16 @@ class Cell:
     config_name: str
     traffic_name: str
     config: dict  # the configuration file
+    model: object  # the module ``models/<config["model"]>.py``
     traffic: dict  # the traffic file
     limits: dict  # number -> limit
     end_to_end: tuple  # metric entries of BENCHMARK.json this cell reports
     per_layer: tuple
+
+    @property
+    def shapes(self) -> dict:
+        """The weight tree's ``{path: (shape, init)}``."""
+        return self.model.weight_shapes(self.config)
 
     @property
     def prefix(self) -> int:
@@ -70,6 +81,19 @@ def _applies(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
 
+def module(kind: str, name: str, root: Path = ROOT):
+    """The module ``<root>/bench/<kind>/<name>.py`` (``kind``: ``models``,
+    ``counts`` or ``metrics``), loaded from its file; raises
+    ``FileNotFoundError`` naming the file where there is none."""
+    path = root / "bench" / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} module {name!r}: looked for {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_benchmark(root: Path = ROOT) -> dict:
     with open(root / "BENCHMARK.json") as f:
         return json.load(f)
@@ -77,7 +101,8 @@ def load_benchmark(root: Path = ROOT) -> dict:
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     """The cell ``name`` of ``<root>/BENCHMARK.json`` with its files from
-    ``<root>/bench``; raises ``KeyError`` for an unknown cell."""
+    ``<root>/bench``; raises ``KeyError`` for an unknown cell and
+    ``FileNotFoundError`` for a configuration whose model has no module."""
     bench = load_benchmark(root)
     entries = {w["name"]: w for w in bench["workloads"]}
     if name not in entries:
@@ -89,12 +114,14 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         with open(files / kind / f"{stem}.json") as f:
             return json.load(f)
 
+    config = read("configs", w["config"])
     return Cell(
         name=name,
         chips=int(w["chips"]),
         config_name=w["config"],
         traffic_name=w["traffic"],
-        config=read("configs", w["config"]),
+        config=config,
+        model=module("models", config["model"], root),
         traffic=read("traffic", w["traffic"]),
         limits=read("limits", name),
         end_to_end=tuple(m for m in bench["end_to_end"] if _applies(m, name)),

@@ -1,8 +1,13 @@
 """Weights and batches made from ``--seed``, on the device, in one jit each.
 
-The program receives only what is made here: the weight tree (in the layout
-its dense decoder takes) and a pool of batches.  The reference reads the same
-weights and batches by calling these functions again with the same seed.
+The program receives only what is made here: the weight tree and a pool of
+batches.  The reference reads the same weights and batches by calling these
+functions again with the same seed.
+
+The weights are made, packed and measured from their shapes alone: the
+``{path: (shape, init)}`` that the configuration's model module gives
+(``bench/models``, ``weight_shapes``).  Leaf ``i`` in sorted path order
+draws from the seed's weight key folded with ``i``.
 
 Batches follow ``repro.data.synthetic.lm_batches``: a planted bigram table
 makes the traffic's ``bigram_follow`` share of the transitions
@@ -31,46 +36,6 @@ def seed_key(seed: int, stream: int) -> jax.Array:
     return jax.random.fold_in(key, stream)
 
 
-def weight_shapes(config: dict) -> dict:
-    """The weight tree's leaf shapes: ``{path: (shape, init)}`` with ``init``
-    one of ``("normal", scale)``, ``("ones",)``, ``("zeros",)``.
-
-    The layers of the one-layer pattern are stacked on a leading axis, as
-    the program's scanned stack keeps them.
-    """
-    if list(config.get("block_pattern", ["global"])) != ["global"] or config.get("num_experts"):
-        raise ValueError("weights: only a dense decoder of global-attention layers is made here")
-    L, d, f = config["num_layers"], config["d_model"], config["d_ff"]
-    hq, hkv, hd, V = config["num_heads"], config["num_kv_heads"], config["head_dim"], config["vocab_size"]
-
-    def norm(prefix):
-        if config["norm_type"] == "layernorm":
-            return {f"{prefix}/scale": (prefix_shape(prefix, d), ("ones",)),
-                    f"{prefix}/bias": (prefix_shape(prefix, d), ("zeros",))}
-        return {f"{prefix}/scale": (prefix_shape(prefix, d), ("zeros",))}
-
-    def prefix_shape(prefix, *shape):
-        return ((L,) if prefix.startswith("stack/") else ()) + shape
-
-    s = {
-        "embed/embedding": ((V, d), ("normal", d**-0.5)),
-        "stack/pos0/attn/wq": ((L, d, hq, hd), ("normal", d**-0.5)),
-        "stack/pos0/attn/wk": ((L, d, hkv, hd), ("normal", d**-0.5)),
-        "stack/pos0/attn/wv": ((L, d, hkv, hd), ("normal", d**-0.5)),
-        "stack/pos0/attn/wo": ((L, hq, hd, d), ("normal", (hq * hd) ** -0.5)),
-        "stack/pos0/mlp/w_up": ((L, d, f), ("normal", d**-0.5)),
-        "stack/pos0/mlp/w_down": ((L, f, d), ("normal", f**-0.5)),
-    }
-    if config["gated_mlp"]:
-        s["stack/pos0/mlp/w_gate"] = ((L, d, f), ("normal", d**-0.5))
-    s.update(norm("stack/pos0/pre_norm"))
-    s.update(norm("stack/pos0/mlp_pre_norm"))
-    s.update(norm("final_norm"))
-    if not config["tie_embeddings"]:
-        s["unembed/embedding"] = ((V, d), ("normal", d**-0.5))
-    return s
-
-
 def _nest(flat: dict) -> dict:
     tree: dict = {}
     for path, leaf in flat.items():
@@ -82,9 +47,9 @@ def _nest(flat: dict) -> dict:
     return tree
 
 
-def _make_weights(config: dict, key):
+def _make_weights(shapes: dict, key):
     out = {}
-    for i, (path, (shape, init)) in enumerate(sorted(weight_shapes(config).items())):
+    for i, (path, (shape, init)) in enumerate(sorted(shapes.items())):
         if init[0] == "normal":
             k = jax.random.fold_in(key, i)
             out[path] = init[1] * jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
@@ -95,10 +60,11 @@ def _make_weights(config: dict, key):
     return _nest(out)
 
 
-def make_weights(config: dict, seed: int):
-    """The f32 weight tree for ``seed``, made on the device in one jit."""
+def make_weights(shapes: dict, seed: int):
+    """The f32 weight tree of ``shapes`` for ``seed``, made on the device in
+    one jit."""
     key = seed_key(seed, _STREAM_WEIGHTS)
-    return jax.jit(functools.partial(_make_weights, config))(key)
+    return jax.jit(functools.partial(_make_weights, shapes))(key)
 
 
 def _leaf(tree: dict, path: str):
@@ -107,25 +73,25 @@ def _leaf(tree: dict, path: str):
     return tree
 
 
-def unflatten(config: dict, flat):
+def unflatten(shapes: dict, flat):
     """The weight tree over a packed ``(N,)`` buffer.
 
     The packing order is ``jax.tree.leaves`` order of the weight tree
     (sorted keys), which is the order the program packs it in.
     """
     out, off = {}, 0
-    for path, (shape, _) in sorted(weight_shapes(config).items()):
+    for path, (shape, _) in sorted(shapes.items()):
         size = int(np.prod(shape))
         out[path] = flat[off:off + size].reshape(shape)
         off += size
     return _nest(out)
 
 
-def leaf_norms(config: dict, tree):
+def leaf_norms(shapes: dict, tree):
     """The f32 norm of each weight of a weight tree, a stacked weight once
     per layer: the weights whose norms ``correct`` compares."""
     out = []
-    for path, (shape, _) in sorted(weight_shapes(config).items()):
+    for path, (shape, _) in sorted(shapes.items()):
         sq = jnp.square(_leaf(tree, path).astype(jnp.float32))
         if path.startswith("stack/"):
             out.append(jnp.sqrt(jnp.sum(sq.reshape(shape[0], -1), axis=1)))
@@ -134,8 +100,8 @@ def leaf_norms(config: dict, tree):
     return jnp.concatenate(out)
 
 
-def param_count(config: dict) -> int:
-    return sum(int(np.prod(shape)) for shape, _ in weight_shapes(config).values())
+def param_count(shapes: dict) -> int:
+    return sum(int(np.prod(shape)) for shape, _ in shapes.values())
 
 
 def _make_pool(config: dict, traffic: dict, key):

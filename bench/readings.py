@@ -44,7 +44,7 @@ def program_reading(cell, seed: int) -> dict:
     batches = followed_batches(cell, pool)
     del timed, spec, pool, reader
     gc.collect()
-    ref = reference.follow(cell.config, cell.traffic, batches, seed=seed)
+    ref = reference.follow(cell.model, cell.config, cell.traffic, batches, seed=seed)
     return {"kind": "program", "seed": seed, **check.gaps(program, ref),
             "first_tick_s": times["first_tick"]}
 
@@ -58,10 +58,10 @@ def stand_in_readings(cell, seed: int) -> list[dict]:
     from bench.window import Program, followed_batches
 
     batches = followed_batches(cell, make_pool(cell.config, cell.traffic, seed))
-    ref = reference.follow(cell.config, cell.traffic, batches, seed=seed)
+    ref = reference.follow(cell.model, cell.config, cell.traffic, batches, seed=seed)
     rows = []
     for kind, opts in (("fp8", {"low": "fp8"}), ("half_batch", {"fault": "half_batch"})):
-        got = reference.follow(cell.config, cell.traffic, batches, seed=seed, **opts)
+        got = reference.follow(cell.model, cell.config, cell.traffic, batches, seed=seed, **opts)
         stand_in = Program(losses=got["losses"], grad_norms=got["grad_norms"],
                            change_norms=got["change_norms"])
         rows.append({"kind": kind, "seed": seed, **check.gaps(stand_in, ref)})

@@ -1,16 +1,12 @@
-"""Plain reference: the dense decoder's loss and gradient, the delayed ring,
-the alpha(tau)-weighted combine and the momentum apply, in float32.
+"""Plain reference: the training recipe around a model's loss and gradient,
+the delayed ring, the alpha(tau)-weighted combine and the momentum apply,
+in float32.
 
-Nothing here imports the program.  It follows the equations the program
-states for these configurations:
+Nothing here imports the program.  The model is the module of
+``bench/models`` that the configuration names (its ``model`` key): its
+weight shapes and its loss.  The recipe follows the equations the program
+states:
 
-* pre-norm decoder blocks: LayerNorm (scale, bias) or RMSNorm (1 + scale);
-  attention with full rotary embeddings on every head dimension (split
-  halves), grouped KV heads, causal softmax; gated SiLU feed-forward;
-  sequential residual; final norm; a tied or untied unembedding;
-* a vision configuration puts its image embeddings in front of the tokens
-  and drops those positions before the head; the loss is the mean
-  cross-entropy of the labelled text positions;
 * async (paper eq. 4, Algorithm 1 as delayed gradients): each tick pushes
   the gradient into a K-slot ring in the ring's dtype, draws W staleness
   values from a Poisson(W) law truncated to the ring, weights the ring row
@@ -32,7 +28,8 @@ Matrix products run at ``highest`` precision.  ``low`` (``"fp8"``) rounds
 every operand of every matrix product, forward and backward, to float8 e4m3
 with a per-tensor scale, accumulating in float32: the control that
 ``correct`` must refuse.  ``fault="half_batch"`` drops the labels of
-the second half of each row's text positions (the mean over the rest).
+the second half of each row's text positions (the mean over the rest),
+for any model.
 """
 
 from __future__ import annotations
@@ -90,80 +87,23 @@ def _mm(low):
 
 
 # ---------------------------------------------------------------------------
-# Model
+# The model's loss, under the recipe's precision and fault
 # ---------------------------------------------------------------------------
 
-def _norm(config, p, x):
-    eps = float(config["norm_eps"])
-    if config["norm_type"] == "layernorm":
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-    return x / jnp.sqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * (1.0 + p["scale"])
-
-
-def _rotary(x, theta):
-    """Full rotary embedding on (B, T, heads, hd): the two halves rotate."""
-    T, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
-    ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
-    a, b = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
-def _block(config, mm, p, x):
-    B, T, _ = x.shape
-    hq, hkv, hd = config["num_heads"], config["num_kv_heads"], config["head_dim"]
-    theta = float(config["rope_theta"])
-    h = _norm(config, p["pre_norm"], x)
-    q = _rotary(mm("btd,dnh->btnh", h, p["attn"]["wq"]), theta) / math.sqrt(hd)
-    k = _rotary(mm("btd,dnh->btnh", h, p["attn"]["wk"]), theta)
-    v = mm("btd,dnh->btnh", h, p["attn"]["wv"])
-    q = q.reshape(B, T, hkv, hq // hkv, hd)
-    s = mm("bqngh,bknh->bngqk", q, k)
-    causal = np.tril(np.ones((T, T), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = mm("bngqk,bknh->bqngh", a, v).reshape(B, T, hq, hd)
-    x = x + mm("btnh,nhd->btd", o, p["attn"]["wo"])
-    h = _norm(config, p["mlp_pre_norm"], x)
-    up = mm("btd,df->btf", h, p["mlp"]["w_up"])
-    if "w_gate" in p["mlp"]:
-        up = jax.nn.silu(mm("btd,df->btf", h, p["mlp"]["w_gate"])) * up
-    else:
-        up = jax.nn.silu(up)
-    return x + mm("btf,fd->btd", up, p["mlp"]["w_down"])
-
-
-def loss(config, params, batch, *, low=None, fault=None):
-    """Mean cross-entropy of the labelled text positions."""
-    mm = _mm(low)
-    x = params["embed"]["embedding"][batch["tokens"]]
-    n_prefix = 0
-    if "prefix_embeds" in batch:
-        n_prefix = batch["prefix_embeds"].shape[1]
-        x = jnp.concatenate([batch["prefix_embeds"], x], axis=1)
-    stack = params["stack"]["pos0"]
-
-    def layer(x, p):
-        return _block(config, mm, p, x), None
-
-    # one layer at a time, recomputed in the backward pass, so the f32
-    # activations of a whole stack are never held at once
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, stack)
-    x = _norm(config, params["final_norm"], x)[:, n_prefix:]
-    head = params.get("unembed", params["embed"])["embedding"]
-    logits = mm("btd,vd->btv", x, head)
+def _fault(batch, fault):
+    """``"half_batch"`` drops the labels of the second half of each row's
+    text positions."""
+    if fault != "half_batch":
+        return batch
     labels = batch["labels"]
-    if fault == "half_batch":
-        keep = np.arange(labels.shape[1]) < labels.shape[1] // 2
-        labels = jnp.where(keep, labels, -1)
-    mask = labels >= 0
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-    return jnp.sum(jnp.where(mask, lse - ll, 0.0)) / jnp.maximum(jnp.sum(mask), 1)
+    keep = np.arange(labels.shape[1]) < labels.shape[1] // 2
+    return dict(batch, labels=jnp.where(keep, labels, -1))
+
+
+def loss(model, config, params, batch, *, low=None, fault=None):
+    """The loss of ``model`` (a module of ``bench/models``) for one batch,
+    its matrix products at the highest precision or at ``low``."""
+    return model.loss(config, _mm(low), params, _fault(batch, fault))
 
 
 # ---------------------------------------------------------------------------
@@ -257,9 +197,9 @@ def combine_weights(taus: np.ndarray, t: int, table: np.ndarray, lr: float, work
 # Following the first ticks
 # ---------------------------------------------------------------------------
 
-def follow(config, traffic, batches, *, seed, low=None, fault=None):
-    """Run the reference over one tick per batch of ``batches``, from the
-    seed's weights.
+def follow(model, config, traffic, batches, *, seed, low=None, fault=None):
+    """Run the reference of ``model`` over one tick per batch of ``batches``,
+    from the seed's weights.
 
     Returns host arrays: ``losses`` (one per tick), ``grad_norms`` (per
     weight, of the first gradient), ``change_norms`` (per weight, of the
@@ -271,8 +211,9 @@ def follow(config, traffic, batches, *, seed, low=None, fault=None):
     W, K = int(traffic.get("workers", 1)), int(traffic.get("ring", 0))
     refit_every = int(traffic.get("refresh_every") or 0)
     ticks = len(batches)
-    grad_fn = jax.jit(jax.value_and_grad(functools.partial(loss, config, low=low, fault=fault)))
-    norms = jax.jit(functools.partial(leaf_norms, config))
+    shapes = model.weight_shapes(config)
+    grad_fn = jax.jit(jax.value_and_grad(functools.partial(loss, model, config, low=low, fault=fault)))
+    norms = jax.jit(functools.partial(leaf_norms, shapes))
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def apply(p, v, u):
@@ -288,13 +229,13 @@ def follow(config, traffic, batches, *, seed, low=None, fault=None):
 
     @jax.jit
     def change(p, p0):
-        return leaf_norms(config, jax.tree.map(jnp.subtract, p, p0))
+        return leaf_norms(shapes, jax.tree.map(jnp.subtract, p, p0))
 
     if engine != "sync":
         taus = tick_taus(seed, ticks, W, K)
         table = initial_table(lr, W, K).astype(np.float32)
         ring_dtype = jnp.dtype(traffic["ring_dtype"])
-    p = make_weights(config, seed)
+    p = make_weights(shapes, seed)
     v = jax.tree.map(jnp.zeros_like, p)
     ring: dict[int, object] = {}  # tick -> its gradient, while a later tick can read it
     losses, grad_norms = [], None
@@ -320,7 +261,7 @@ def follow(config, traffic, batches, *, seed, low=None, fault=None):
         p, v = apply(p, v, u)
         del u
     del v, ring
-    delta = change(p, make_weights(config, seed))
+    delta = change(p, make_weights(shapes, seed))
     return {
         "losses": np.asarray(jnp.stack(losses), np.float64),
         "grad_norms": np.asarray(grad_norms, np.float64),

@@ -4,19 +4,22 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up builds the program's engine from the cell's files (``cells.py``),
-with weights and a pool of batches made on the device from ``--seed``
-(``data.py``), and drives it through its first refresh period (``window.py``).
+with weights (of the shapes the configuration's ``models/`` module gives)
+and a pool of batches made on the device from ``--seed`` (``data.py``), and
+drives it through its first refresh period (``window.py``).
 Then:
 
 * ``--trace 0``: the measured window runs whole refresh periods for
   ``--seconds`` and gives the end-to-end metrics (``tokens_per_s``,
   ``tick_ms_p95``, ``setup_s``);
 * ``--trace 1``: two refresh periods run under the profiler and the
-  per-layer metrics are read from that trace (``trace.py``, ``metrics/``).
+  per-layer metrics are read from that trace, with the program's own spans
+  and scopes (``trace.py``, ``program_trace.py``, ``metrics/``).
 
 Either way the ticks that set-up drove (``Cell.followed_ticks``) are then
-compared with the plain reference (``reference.py``, ``check.py``), once the window is over and the
-program's state is freed.  Each compared number is printed beside its limit
+compared with the plain reference (``reference.py`` around the model's
+loss, ``check.py``), once the window is over and the program's state is
+freed.  Each compared number is printed beside its limit
 as the last lines of standard error; the last line of standard output is the
 result as one JSON object.
 
@@ -32,7 +35,6 @@ _T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -55,7 +57,7 @@ class TraceRecord:
     """What a per-layer metric reader gets (see ``metrics/__init__.py``)."""
 
     cell: object
-    reduced: object  # trace.Reduced
+    reduced: object  # program_trace.Scoped (a trace.Reduced with the program's marks)
     ticks: int
     chips: int
     peak: dict
@@ -70,14 +72,6 @@ def peaks(kind: str, root: Path = ROOT) -> dict:
     if kind not in table:
         raise KeyError(f"device kind {kind!r} has no peaks in bench/peaks.json")
     return table[kind]
-
-
-def _module(root: Path, kind: str, name: str):
-    path = root / "bench" / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def p95(values) -> float:
@@ -99,9 +93,11 @@ def _device_info(chips: int) -> dict:
 
 
 def _traced_window(timed, spec, pool, cell):
-    """Two refresh periods under the profiler; returns (state, stats, plain)."""
+    """Two refresh periods under the profiler; returns (state, stats, plain),
+    the plain form with the program's spans and scopes (``program_trace``)."""
     import jax
 
+    from bench import program_trace
     from bench import trace as tr
     from bench.window import window
 
@@ -109,21 +105,24 @@ def _traced_window(timed, spec, pool, cell):
     with jax.profiler.trace(str(TRACE_DIR)):
         with jax.profiler.TraceAnnotation("window"):
             state, stats = window(timed, spec, pool, cell, 0.0, max_chunks=2)
+    t0 = time.perf_counter()
     path = tr.find_xplane(str(TRACE_DIR))
-    plain = tr.load(path, [d.id for d in jax.devices()[: cell.chips]])
+    plain = program_trace.load(path, [d.id for d in jax.devices()[: cell.chips]])
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    log(f"trace read in {time.perf_counter() - t0:.3f} s")
     return state, stats, plain
 
 
 def _per_layer(cell, plain, stats, seed: int, chips: int, kind: str, root: Path):
-    from bench import trace as tr
+    from bench.cells import module
     from bench.data import param_count
+    from bench.program_trace import Scoped
     from bench.reference import tick_taus
 
-    reduced = tr.Reduced(plain)
+    reduced = Scoped(plain)
     t = cell.traffic
-    n = param_count(cell.config)
-    update = _module(root, "counts", t["update_count"])
+    n = param_count(cell.shapes)
+    update = module("counts", t["update_count"], root)
     first = cell.followed_ticks
     ticks = stats["ticks"]
     if t["engine"] != "sync":
@@ -131,18 +130,22 @@ def _per_layer(cell, plain, stats, seed: int, chips: int, kind: str, root: Path)
         costs = [update.update_cost(t, n, taus[i], i) for i in range(first, first + ticks)]
     else:
         costs = [update.update_cost(t, n)] * ticks
-    model = _module(root, "counts", cell.config["count"])
+    flops = module("counts", cell.config["count"], root)
     rec = TraceRecord(
         cell=cell, reduced=reduced, ticks=ticks, chips=chips, peak=peaks(kind, root),
-        flops_per_tick=model.train_flops(cell.config, int(t["batch"]), int(t["positions"])),
+        flops_per_tick=flops.train_flops(cell.config, int(t["batch"]), int(t["positions"])),
         update_costs=costs, refresh_s=list(stats["refresh_s"]),
     )
     metrics = {}
     for m in cell.per_layer:
-        value = _module(root, "metrics", m["name"]).read(rec)
+        value = module("metrics", m["name"], root).read(rec)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-    breakdown = {"device_ops": reduced.top_ops(10), "idle_gaps": reduced.idle_gaps(10)}
+    # the parts of fwd_bwd_ms by the program's scopes; they add up to it
+    split = {k: 1e3 * v / ticks for k, v in reduced.other_by_scope().items()}
+    log(f"step body, ms a tick: {split}, their sum {sum(split.values())!r}")
+    breakdown = {"device_ops": reduced.top_ops(10), "idle_gaps": reduced.idle_gaps(10),
+                 "idle_gaps_program": reduced.idle_gaps_program(10)}
     busy = {"busy_s": reduced.mean_busy_s(), "window_s": reduced.window_s}
     return metrics, breakdown, busy
 
@@ -185,7 +188,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, root: Path = ROOT,
     del state, timed, reader, pool, spec
     gc.collect()
 
-    ref = reference.follow(cell.config, cell.traffic, batches, seed=seed)
+    ref = reference.follow(cell.model, cell.config, cell.traffic, batches, seed=seed)
     numbers = check.gaps(program, ref)
     nonfinite = int(np.sum(~np.isfinite(losses)))
     checked = check.checks(numbers, cell.limits, retraces=int(stats["retraces"]), nonfinite=nonfinite)

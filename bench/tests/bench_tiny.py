@@ -1,6 +1,7 @@
 """A checkout of the benchmark with one more cell, at tiny widths, for the
 CPU tests: the real ``BENCHMARK.json`` and files, plus a configuration, a
-traffic mix, limits and an entry that exist only as new files."""
+traffic mix, limits, an entry and, where a test gives one, a model module
+that exist only as new files."""
 
 from __future__ import annotations
 
@@ -19,7 +20,8 @@ for p in (ROOT, ROOT / "src"):
 # not average bf16's rounding as the cells' 512 and more do, so the cells'
 # limits would not hold for it; what these tests check is the harness.
 TINY_CONFIG = {
-    "arch": "stablelm-1.6b", "source": "tiny widths for the CPU tests", "count": "dense_decoder",
+    "arch": "stablelm-1.6b", "source": "tiny widths for the CPU tests",
+    "model": "dense_decoder", "count": "dense_decoder",
     "num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 4, "head_dim": 16,
     "d_ff": 128, "vocab_size": 256, "block_pattern": ["global"], "norm_type": "layernorm",
     "norm_eps": 1e-06, "act": "silu", "gated_mlp": True, "parallel_residual": False,
@@ -41,12 +43,15 @@ def tiny_traffic(name: str) -> dict:
     return t
 
 
-def make_root(tmp: Path, cells: dict, *, limits_from: str = "stablelm-async-1x512") -> Path:
+def make_root(tmp: Path, cells: dict, *, limits_from: str = "stablelm-async-1x512",
+              models: dict | None = None) -> Path:
     """A copy of the benchmark under ``tmp`` with ``cells`` added:
     ``{cell: (config dict, traffic name)}``; each new cell takes the limits
-    of ``limits_from``."""
+    of ``limits_from``.  ``models`` adds model modules: ``{name: source}``."""
     root = tmp / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench", ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    for name, source in (models or {}).items():
+        (root / "bench" / "models" / f"{name}.py").write_text(source)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     limits = json.loads((ROOT / "bench" / "limits" / f"{limits_from}.json").read_text())
     for cell, (config, traffic) in cells.items():

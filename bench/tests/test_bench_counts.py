@@ -41,15 +41,17 @@ def test_model_flops_match_the_reference_forward(config):
     import jax
 
     from bench import reference
+    from bench.cells import module
     from bench.counts.dense_decoder import train_flops
     from bench.data import _make_pool, _make_weights
 
+    model = module("models", config["model"])
     traffic = {"batch": 2, "positions": 32, "pool": 1, "bigram_follow": 0.8}
     key = jax.random.PRNGKey(0)
-    params = jax.eval_shape(lambda k: _make_weights(config, k), key)
+    params = jax.eval_shape(lambda k: _make_weights(model.weight_shapes(config), k), key)
     pool = jax.eval_shape(lambda k: _make_pool(config, traffic, k), key)
     batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), pool)
-    jaxpr = jax.make_jaxpr(functools.partial(reference.loss, config))(params, batch)
+    jaxpr = jax.make_jaxpr(functools.partial(reference.loss, model, config))(params, batch)
     counted = _dot_flops(jaxpr.jaxpr)
     # the reference scores every (query, key) pair and masks; the count
     # keeps the pairs a causal mask keeps, counted here one by one
@@ -91,17 +93,18 @@ def test_peaks_by_device_kind():
 
 def test_metrics_read_none_when_nothing_to_read():
     from bench import trace as tr
-    from bench.run import TraceRecord, _module
+    from bench.cells import module
+    from bench.run import TraceRecord
 
     plain = {"window_ns": [0.0, 1e9], "devices": {"0": [["fusion.1", 0.0, 5e8]]}, "host": []}
     rec = TraceRecord(cell=None, reduced=tr.Reduced(plain), ticks=10, chips=1,
                       peak={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9},
                       flops_per_tick=1e9, update_costs=[(1.0, 1e6)] * 10, refresh_s=[])
-    assert _module(ROOT, "metrics", "update_roofline").read(rec) is None
-    assert _module(ROOT, "metrics", "refresh_ms").read(rec) is None
-    assert _module(ROOT, "metrics", "idle_share").read(rec) == pytest.approx(50.0)
-    assert _module(ROOT, "metrics", "mfu").read(rec) == pytest.approx(1.0)
-    assert _module(ROOT, "metrics", "fwd_bwd_ms").read(rec) == pytest.approx(50.0)
+    assert module("metrics", "update_roofline", ROOT).read(rec) is None
+    assert module("metrics", "refresh_ms", ROOT).read(rec) is None
+    assert module("metrics", "idle_share", ROOT).read(rec) == pytest.approx(50.0)
+    assert module("metrics", "mfu", ROOT).read(rec) == pytest.approx(1.0)
+    assert module("metrics", "fwd_bwd_ms", ROOT).read(rec) == pytest.approx(50.0)
     assert np.isfinite(rec.reduced.window_s)
 
 

@@ -87,9 +87,9 @@ def _rec(plain, ticks):
 
 
 def _read(name, rec):
-    from bench.run import _module
+    from bench.cells import module
 
-    return _module(bench_tiny.ROOT, "metrics", name).read(rec)
+    return module("metrics", name, bench_tiny.ROOT).read(rec)
 
 
 @pytest.mark.parametrize("path,part", [

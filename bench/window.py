@@ -110,11 +110,11 @@ class Program:
     change_norms: np.ndarray
 
 
-def _change_norms(config, flat, key):
+def _change_norms(shapes, flat, key):
     from bench.data import _make_weights, leaf_norms, unflatten
 
-    p0 = _make_weights(config, key)
-    return leaf_norms(config, jax.tree.map(jnp.subtract, unflatten(config, flat), p0))
+    p0 = _make_weights(shapes, key)
+    return leaf_norms(shapes, jax.tree.map(jnp.subtract, unflatten(shapes, flat), p0))
 
 
 class Reader(Hook):
@@ -133,8 +133,9 @@ class Reader(Hook):
 
         self.cell = cell
         self.key = seed_key(seed, _STREAM_WEIGHTS)
-        self.norms = jax.jit(lambda flat: leaf_norms(cell.config, unflatten(cell.config, flat)))
-        self.change = jax.jit(functools.partial(_change_norms, cell.config))
+        shapes = cell.shapes
+        self.norms = jax.jit(lambda flat: leaf_norms(shapes, unflatten(shapes, flat)))
+        self.change = jax.jit(functools.partial(_change_norms, shapes))
         self.grad_norms = self.change_norms = None
         self.losses = []
         self.ticks = 0
@@ -193,7 +194,7 @@ def setup(cell, seed: int, *, annotate: bool = False):
     times = {}
     t0 = time.perf_counter()
     cfg = model_config(cell.config)
-    weights = make_weights(cell.config, seed)
+    weights = make_weights(cell.shapes, seed)
     spec = run_spec(cell, cfg, weights, seed=seed)
     engine = make_engine(spec)
     state = engine.build()
