@@ -9,6 +9,7 @@ from repro.configs.base import ModelConfig, get_config, list_configs, reduced, r
 # Register the 10 assigned architectures (import side effects).
 from repro.configs import (  # noqa: F401
     codeqwen1_5_7b,
+    deepseek_v2_lite,
     falcon_mamba_7b,
     gemma2_27b,
     gemma3_27b,

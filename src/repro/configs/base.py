@@ -12,7 +12,19 @@ import dataclasses
 import math
 from typing import Callable
 
-__all__ = ["ModelConfig", "register", "get_config", "list_configs", "reduced"]
+__all__ = ["ModelConfig", "Yarn", "register", "get_config", "list_configs", "reduced"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type ``yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +53,13 @@ class ModelConfig:
     attn_logit_softcap: float | None = None  # gemma2: 50.0
     final_logit_softcap: float | None = None  # gemma2: 30.0
     query_scale: float | None = None  # default 1/sqrt(head_dim)
+    # multi-head latent attention (deepseek-v2), on when kv_lora_rank > 0:
+    # head_dim is the no-rope part of each q/k head, qk_rope_head_dim the
+    # rotary part (one k_rope shared by every head), v_head_dim the v width
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Yarn | None = None
 
     # mlp --------------------------------------------------------------------
     act: str = "silu"  # silu | gelu
@@ -50,6 +69,7 @@ class ModelConfig:
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm (whisper/stablelm)
     use_post_norms: bool = False  # gemma2/3 sandwich norms
     use_rope: bool = True  # whisper uses absolute sinusoidal instead
+    first_k_dense_replace: int = 0  # leading dense-MLP layers before an expert stack
     parallel_residual: bool = False  # stablelm-2: attn & mlp share the residual
 
     # embeddings -------------------------------------------------------------
@@ -65,6 +85,15 @@ class ModelConfig:
     shared_expert_ff: int = 0  # qwen2-moe: 4 shared experts fused into one MLP
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
+    # this chip's share of expert parallelism (0 = every expert, with the
+    # capacity path above): experts [expert_offset, expert_offset +
+    # experts_held) of num_experts live here; routing stays over all
+    # num_experts, no token is dropped, and the load-balance loss is
+    # deepseek-v2's per-sequence one
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True  # held experts: renormalise the top-k gates (qwen)
+    shared_expert_gate: bool = True  # qwen2-moe's sigmoid gate on the shared MLP
 
     # ssm (mamba-1) ------------------------------------------------------------
     ssm_state: int = 16
@@ -125,19 +154,25 @@ class ModelConfig:
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // self.pattern_period
+        return (self.num_layers - self.first_k_dense_replace) // self.pattern_period
 
     @property
     def remainder_layers(self) -> tuple[str, ...]:
-        rem = self.num_layers - self.num_periods * self.pattern_period
+        rem = self.num_layers - self.first_k_dense_replace - self.num_periods * self.pattern_period
         return self.block_pattern[:rem]
 
     @property
     def experts_padded(self) -> int:
         return self.num_experts_padded or self.num_experts
 
+    @property
+    def v_dim(self) -> int:
+        """Width of each attention head's values (MLA's v_head_dim)."""
+        return self.v_head_dim or self.head_dim
+
     def layer_types(self) -> tuple[str, ...]:
-        return self.block_pattern * self.num_periods + self.remainder_layers
+        lead = ("global",) * self.first_k_dense_replace
+        return lead + self.block_pattern * self.num_periods + self.remainder_layers
 
     def supports_long_context(self) -> bool:
         """True iff every mixing layer is sub-quadratic (local/ssm/recurrent)."""
@@ -149,9 +184,16 @@ class ModelConfig:
         n = v * d  # embedding (tied unembed adds nothing)
         if not self.tie_embeddings:
             n += v * d
-        for t in self.layer_types():
+        n += d  # final norm
+        for i, t in enumerate(self.layer_types()):
             n += 2 * d  # pre norms (attn+mlp scale vectors, approximation)
-            if t in ("global", "local"):
+            if t in ("global", "local") and self.kv_lora_rank:
+                h, r, dr = self.num_heads, self.kv_lora_rank, self.qk_rope_head_dim
+                n += d * h * (self.head_dim + dr)  # wq
+                n += d * (r + dr) + r  # wkv_a, kv_norm
+                n += r * h * (self.head_dim + self.v_dim)  # wkv_b
+                n += h * self.v_dim * d  # wo
+            elif t in ("global", "local"):
                 n += d * self.num_heads * self.head_dim  # wq
                 n += 2 * d * self.num_kv_heads * self.head_dim  # wk wv
                 n += self.num_heads * self.head_dim * d  # wo
@@ -162,12 +204,13 @@ class ModelConfig:
                 w = self.lru_width or d
                 n += d * w * 2 + w * self.ssm_conv + 3 * w + w * d  # two in-proj branches, conv, gates/Lambda, out
             if t != "ssm":  # every non-mamba block carries an MLP/MoE
-                if self.num_experts:
+                if self.num_experts and i >= self.first_k_dense_replace:
                     e, fe = self.num_experts, self.d_ff_expert
                     n += d * e  # router
-                    n += e * (3 * d * fe if self.gated_mlp else 2 * d * fe)
+                    n += (self.experts_held or e) * (3 * d * fe if self.gated_mlp else 2 * d * fe)
                     if self.shared_expert_ff:
-                        n += 3 * d * self.shared_expert_ff + d  # shared MLP + gate
+                        n += 3 * d * self.shared_expert_ff  # shared MLP
+                        n += d if self.shared_expert_gate else 0
                 else:
                     n += 3 * d * self.d_ff if self.gated_mlp else 2 * d * self.d_ff
         if self.is_encoder_decoder:
@@ -185,8 +228,9 @@ class ModelConfig:
             return self.param_count()
         d, fe = self.d_model, self.d_ff_expert
         per_expert = 3 * d * fe if self.gated_mlp else 2 * d * fe
-        inactive = (self.num_experts - self.top_k) * per_expert * len(
-            [t for t in self.layer_types() if t != "ssm"]
+        types = self.layer_types()[self.first_k_dense_replace:]
+        inactive = ((self.experts_held or self.num_experts) - self.top_k) * per_expert * len(
+            [t for t in types if t != "ssm"]
         )
         return self.param_count() - inactive
 
@@ -237,6 +281,8 @@ def reduced(cfg: ModelConfig, *, d_model: int = 256, periods: int = 2) -> ModelC
         remat=False,
         use_pallas=False,
     )
+    if cfg.first_k_dense_replace:
+        upd.update(num_layers=cfg.first_k_dense_replace + num_layers)
     if cfg.num_experts:
         upd.update(
             num_experts=4,
@@ -245,6 +291,10 @@ def reduced(cfg: ModelConfig, *, d_model: int = 256, periods: int = 2) -> ModelC
             d_ff_expert=d_model,
             shared_expert_ff=d_model if cfg.shared_expert_ff else 0,
         )
+        if cfg.experts_held:
+            upd.update(experts_held=2, expert_offset=0)
+    if cfg.kv_lora_rank:
+        upd.update(kv_lora_rank=64, qk_rope_head_dim=16, v_head_dim=64)
     if cfg.lru_width:
         upd.update(lru_width=d_model)
     if cfg.is_encoder_decoder:

@@ -10,6 +10,11 @@ Three compute paths, all numerically equivalent where they overlap:
   layers O(S·W) in FLOPs and bytes (this is what legitimizes ``long_500k``).
 * ``decode_attention`` — one query token against a full or ring-buffer cache.
 
+``mla_layer`` is deepseek-v2's multi-head latent attention on the
+full-sequence path: keys and values come up from a normed low-rank latent,
+one rotary key is shared by every head, and values are narrower than keys;
+it mixes through ``blockwise_attention`` like every other layer.
+
 GQA is handled by grouping query heads over KV heads; logit softcapping
 (gemma2) is applied pre-softmax.  The Pallas flash kernel
 (:mod:`repro.kernels.flash_attention`) is a drop-in for the inner block loop
@@ -23,10 +28,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import LayerIO, Params, apply_rope, truncated_normal
+from repro.models.layers import (
+    LayerIO,
+    Params,
+    apply_rmsnorm,
+    apply_rope,
+    init_rmsnorm,
+    truncated_normal,
+    yarn_mscale,
+)
 from repro.sharding.ctx import shard_activation
 
 NEG_INF = -2.0e38
+# Above this many bytes of f32 scores a call (batch x heads x queries x
+# keys), the backward pass recomputes each query block, and within it each
+# key block's scores, instead of keeping the scores: at 2 x 4096 positions
+# and 16 heads they alone would be 2 GiB a layer, several times over.
+REMAT_SCORES_BYTES = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +120,7 @@ def blockwise_attention(
     block_k: int = 512,
 ) -> jnp.ndarray:
     B, S, Nq, H = q.shape
-    T, Nkv = k.shape[1], k.shape[2]
+    T, Nkv, Hv = k.shape[1], k.shape[2], v.shape[-1]  # v may be narrower (MLA)
     G = Nq // Nkv
     q = q.reshape(B, S, Nkv, G, H)
 
@@ -124,8 +142,9 @@ def blockwise_attention(
             kpos = jnp.pad(kpos, ((0, 0), (0, pad_k)), constant_values=-1)
         nkb = (T + pad_k) // bk
         kb = k.reshape(B, nkb, bk, Nkv, H)
-        vb = v.reshape(B, nkb, bk, Nkv, H)
+        vb = v.reshape(B, nkb, bk, Nkv, Hv)
         kposb = kpos.reshape(B, nkb, bk)
+        remat = 4 * B * Nq * S * T > REMAT_SCORES_BYTES
 
         def per_q_block(args):
             qblk, qposblk = args  # (B, bq, Nkv, G, H), (B, bq)
@@ -140,9 +159,9 @@ def blockwise_attention(
 
             m0 = jnp.full((B, Nkv, G, bq), NEG_INF, jnp.float32)
             l0 = jnp.zeros((B, Nkv, G, bq), jnp.float32)
-            a0 = jnp.zeros((B, bq, Nkv, G, H), jnp.float32)
+            a0 = jnp.zeros((B, bq, Nkv, G, Hv), jnp.float32)
             (m, l, acc), _ = jax.lax.scan(
-                kv_step,
+                jax.checkpoint(kv_step) if remat else kv_step,
                 (m0, l0, a0),
                 (kb.transpose(1, 0, 2, 3, 4), vb.transpose(1, 0, 2, 3, 4), kposb.transpose(1, 0, 2)),
             )
@@ -151,10 +170,12 @@ def blockwise_attention(
 
         qblocks = q.reshape(B, nqb, bq, Nkv, G, H).transpose(1, 0, 2, 3, 4, 5)
         qposblocks = qpos.reshape(B, nqb, bq).transpose(1, 0, 2)
-        out = jax.lax.map(per_q_block, (qblocks, qposblocks))  # (nqb, B, bq, Nkv, G, H)
-        out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, nqb * bq, Nkv, G, H)
+        if remat:
+            per_q_block = jax.checkpoint(per_q_block)
+        out = jax.lax.map(per_q_block, (qblocks, qposblocks))  # (nqb, B, bq, Nkv, G, Hv)
+        out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, nqb * bq, Nkv, G, Hv)
 
-    out = out[:, :S].reshape(B, S, Nq, H)
+    out = out[:, :S].reshape(B, S, Nq, Hv)
     return out.astype(v.dtype)
 
 
@@ -186,7 +207,7 @@ def _banded_attention(q, k, v, qpos, kpos, bq, window, softcap, causal):
         return pv / l.transpose(0, 3, 1, 2)[..., None]
 
     out = jax.lax.map(per_q_block, jnp.arange(nqb))
-    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, Spad, Nkv, G, H)
+    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, Spad, Nkv, G, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -334,4 +355,52 @@ def attention_layer(
         block_k=cfg.attn_block_k,
     )
     out = shard_activation(out, ("batch", "seq", "heads", None))
+    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(dt))
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (deepseek-v2, no q low-rank)
+# ---------------------------------------------------------------------------
+
+def init_mla(key, cfg) -> Params:
+    d, h, dn, dr = cfg.d_model, cfg.num_heads, cfg.head_dim, cfg.qk_rope_head_dim
+    r, dv = cfg.kv_lora_rank, cfg.v_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    dt = jnp.float32
+    return {
+        "wq": truncated_normal(kq, (d, h, dn + dr), d**-0.5, dt),
+        "wkv_a": truncated_normal(ka, (d, r + dr), d**-0.5, dt),
+        "kv_norm": init_rmsnorm(r),
+        "wkv_b": truncated_normal(kb, (r, h, dn + dv), r**-0.5, dt),
+        "wo": truncated_normal(ko, (h, dv, d), (h * dv) ** -0.5, dt),
+    }
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(no-rope + rope dims)^-0.5, times YaRN's ``mscale_all_dim`` factor squared."""
+    scale = (cfg.head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def mla_layer(p: Params, x: jnp.ndarray, io: LayerIO, cfg) -> jnp.ndarray:
+    """x: (B, S, D) -> (B, S, D).  Rope runs on split halves of the rope dims
+    (the published code first permutes interleaved pairs into halves)."""
+    dt = x.dtype
+    dn, r = cfg.head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(dt))
+    kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt))
+    latent = apply_rmsnorm(p["kv_norm"], kv_a[..., :r], cfg.norm_eps)
+    kv = jnp.einsum("bsr,rnh->bsnh", latent, p["wkv_b"].astype(dt))
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    q_rope = apply_rope(q[..., dn:], io.positions, cfg.rope_theta, cfg.yarn)
+    k_rope = apply_rope(kv_a[..., None, r:], io.positions, cfg.rope_theta, cfg.yarn)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1) * jnp.asarray(mla_softmax_scale(cfg), dt)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + k_rope.shape[-1:])], axis=-1)
+    out = blockwise_attention(
+        q, k, v, io.positions, io.positions,
+        causal=io.causal, window=None, softcap=None,
+        block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+    )
     return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(dt))

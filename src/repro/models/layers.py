@@ -9,6 +9,7 @@ Sharding is applied *by name* through :mod:`repro.sharding.specs`.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -120,13 +121,40 @@ def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's inverse frequencies (deepseek-v2's ``DeepseekV2YarnRotaryEmbedding``):
+    the plain ones above the correction range of ``beta_fast`` rotations,
+    divided by ``factor`` below that of ``beta_slow``, a linear ramp between."""
+    def correction_dim(rotations):
+        return head_dim * math.log(yarn.original_max_position_embeddings / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    extra = rope_frequencies(head_dim, theta)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, yarn=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    ``yarn`` (a ``configs.Yarn``) scales the frequencies and cos/sin."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(hd, theta), jnp.float32)
+    inv = rope_frequencies(hd, theta) if yarn is None else yarn_frequencies(hd, theta, yarn)
+    freqs = jnp.asarray(inv, jnp.float32)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

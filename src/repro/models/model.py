@@ -98,21 +98,27 @@ def forward(params: Params, batch: dict[str, Any], cfg) -> tuple[jnp.ndarray, jn
     For vlm configs the prefix positions are dropped from the logits so the
     output always aligns with ``batch["tokens"]``.
     """
+    logits, stats = _forward(params, batch, cfg)
+    return logits, stats["aux"]
+
+
+def _forward(params, batch, cfg):
+    """:func:`forward` with all the stack's stats (``transformer.zero_stats``)."""
     act_dt = dtype_of(cfg.activation_dtype)
     if cfg.is_encoder_decoder:
         memory = W.encode(params, batch["enc_embeds"].astype(act_dt), cfg)
         logits = W.decode_train(params, batch["tokens"], memory, cfg)
-        return logits, jnp.zeros((), jnp.float32)
+        return logits, {"aux": jnp.zeros((), jnp.float32)}
 
     x, positions, n_prefix = _embed_with_prefix(params, batch, cfg, act_dt)
     io = LayerIO(positions=positions, causal=True)
-    x, aux = T.apply_stack(params["stack"], x, io, cfg)
+    x, stats = T.apply_stack(params["stack"], x, io, cfg)
     x = _final_norm(cfg, params["final_norm"], x)
     if n_prefix:
         x = x[:, n_prefix:]
     unembed = params.get("unembed", params["embed"])
     logits = apply_unembed(unembed, x, softcap=cfg.final_logit_softcap)
-    return logits, aux
+    return logits, stats
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +138,11 @@ def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> tuple[jnp.ndarray
 
 
 def loss_fn(params: Params, batch: dict[str, Any], cfg) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
-    logits, aux = forward(params, batch, cfg)
+    """-> (loss, metrics): ``ce``, ``aux``, ``n_tokens``, and for a chip's
+    share of experts the tick's counters ``moe_held_choices`` (token-choices
+    routed to held experts, all layers) and ``moe_max_expert_load``."""
+    logits, stats = _forward(params, batch, cfg)
+    aux = stats["aux"]
     labels = batch.get("labels")
     if labels is None:  # next-token objective derived from tokens
         labels = jnp.concatenate(
@@ -140,7 +150,7 @@ def loss_fn(params: Params, batch: dict[str, Any], cfg) -> tuple[jnp.ndarray, di
         )
     ce, n_tok = cross_entropy(logits, labels)
     loss = ce + cfg.router_aux_coef * aux if cfg.num_experts else ce
-    return loss, {"ce": ce, "aux": aux, "n_tokens": n_tok}
+    return loss, {"ce": ce, "n_tokens": n_tok, **stats}
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,12 @@ Design (TPU-native, FLOPs-honest):
 
 Without an active mesh (CPU unit tests) the same math runs single-shard —
 that path is the oracle the sharded path is tested against.
+
+:func:`apply_held_moe` is one chip's share of expert parallelism
+(deepseek-v2): the layer holds ``experts_held`` of ``num_experts`` experts,
+routes over all of them, and runs every token-choice that went to a held
+expert (no capacity, no drops) through grouped matmuls; experts held
+elsewhere add nothing here.
 """
 
 from __future__ import annotations
@@ -36,11 +42,12 @@ from repro.sharding.ctx import current_rules
 
 
 def init_moe(key, cfg) -> Params:
-    d, e, fe = cfg.d_model, cfg.experts_padded, cfg.d_ff_expert
+    d, fe = cfg.d_model, cfg.d_ff_expert
+    e = cfg.experts_held or cfg.experts_padded  # expert stacks hold this chip's share
     ks = jax.random.split(key, 6)
     s = 1.0 / np.sqrt(d)
     p: Params = {
-        "router": truncated_normal(ks[0], (d, e), s, jnp.float32),
+        "router": truncated_normal(ks[0], (d, cfg.experts_padded), s, jnp.float32),
         "w_gate_e": truncated_normal(ks[1], (e, d, fe), s, jnp.float32),
         "w_up_e": truncated_normal(ks[2], (e, d, fe), s, jnp.float32),
         "w_down_e": truncated_normal(ks[3], (e, fe, d), 1.0 / np.sqrt(fe), jnp.float32),
@@ -53,8 +60,9 @@ def init_moe(key, cfg) -> Params:
             "w_down": truncated_normal(
                 jax.random.fold_in(ks[4], 2), (fs, d), 1.0 / np.sqrt(fs), jnp.float32
             ),
-            "gate_proj": truncated_normal(ks[5], (d, 1), s, jnp.float32),
         }
+        if cfg.shared_expert_gate:
+            p["shared"]["gate_proj"] = truncated_normal(ks[5], (d, 1), s, jnp.float32)
     return p
 
 
@@ -241,11 +249,141 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, jnp.ndarray]
         out = out.reshape(B, S, D)
 
     if "shared" in p:
-        dt = x.dtype
-        sp = p["shared"]
-        g = _act(cfg.act)(jnp.einsum("bsd,df->bsf", x, sp["w_gate"].astype(dt)))
-        u = jnp.einsum("bsd,df->bsf", x, sp["w_up"].astype(dt))
-        sh = jnp.einsum("bsf,fd->bsd", g * u, sp["w_down"].astype(dt))
-        sgate = jax.nn.sigmoid(jnp.einsum("bsd,dk->bsk", x, sp["gate_proj"].astype(dt)))
-        out = out + sgate * sh
+        out = out + _shared(p["shared"], x, cfg)
     return out, aux
+
+
+def _shared(sp: Params, x: jnp.ndarray, cfg) -> jnp.ndarray:
+    """The shared experts, one gated MLP on every token, with qwen2-moe's
+    sigmoid output gate where the params hold one."""
+    dt = x.dtype
+    g = _act(cfg.act)(jnp.einsum("bsd,df->bsf", x, sp["w_gate"].astype(dt)))
+    u = jnp.einsum("bsd,df->bsf", x, sp["w_up"].astype(dt))
+    sh = jnp.einsum("bsf,fd->bsd", g * u, sp["w_down"].astype(dt))
+    if "gate_proj" in sp:
+        sh = jax.nn.sigmoid(jnp.einsum("bsd,dk->bsk", x, sp["gate_proj"].astype(dt))) * sh
+    return sh
+
+
+# ---------------------------------------------------------------------------
+# This chip's share of expert parallelism (deepseek-v2 routing, no drops)
+# ---------------------------------------------------------------------------
+
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """Tiles of the Pallas grouped matmul: 512 rows where they divide the
+    rows, else 128; 512 deep; the whole width up to 1408 (an expert's
+    d_ff), else 1024 (d_model 2048 in two)."""
+    return (512 if m % 512 == 0 else 128, 512, n if n <= 1408 else 1024)
+
+
+def _grouped_matmul(lhs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+    """``lhs`` rows grouped by held expert, ``sizes`` rows each, then the rows
+    of absent experts (``sizes[-1]``), times each group's ``w[g]``; rows of
+    absent experts come out zero and cost nothing.
+
+    On the TPU this is the Pallas megablox ``gmm``, whose grid visits only
+    the held groups' row tiles; elsewhere ``lax.ragged_dot``.
+    """
+    from repro.kernels import ON_TPU
+
+    w = w.astype(lhs.dtype)
+    if ON_TPU:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(lhs, w, sizes, lhs.dtype, _gmm_tiling)
+    return jax.lax.ragged_dot(lhs, w, sizes[:-1], preferred_element_type=lhs.dtype)
+
+
+def _sequence_balance_loss(probs: jnp.ndarray, topk_idx: jnp.ndarray, top_k: int) -> jnp.ndarray:
+    """deepseek-v2's expert-level balance loss per sequence (``seq_aux``):
+    ``mean_b sum_e f_be P_be`` with ``f_be`` the share of row b's token-choices
+    that chose expert e over its uniform share ``1 / E``, and ``P_be`` the
+    mean router probability of e over row b.  probs: (B, S, E); topk_idx:
+    (B, S, K)."""
+    B, S, E = probs.shape
+    counts = jnp.zeros((B, E), jnp.float32).at[
+        jnp.arange(B)[:, None], topk_idx.reshape(B, S * top_k)
+    ].add(1.0)
+    f = counts / (S * top_k / E)
+    return jnp.mean(jnp.sum(f * jnp.mean(probs, axis=1), axis=-1))
+
+
+# Tokens dispatched to the held experts at a time, at most.  The expert
+# layer's backward holds its dispatch buffers for one block of tokens
+# (recomputed block by block), so their memory does not grow with the tokens
+# a tick: 2 x 4096 tokens at top-6 would otherwise hold ~1.4 GB of (token,
+# choice) rows beside the async state.  A block's tokens are a multiple of
+# ROW_TILE, so that its token-choices fill whole row tiles of the grouped
+# matmul (``_gmm_tiling``).
+DISPATCH_TOKENS = 2048
+ROW_TILE = 128
+
+
+def _held_part(p: Params, xt, topk_p, topk_idx, cfg):
+    """The held experts' part of the layer for tokens xt (T, D) whose
+    top-k gates and expert ids are (T, K); and each held expert's load,
+    with the choices of absent experts last (held + 1,)."""
+    T, D = xt.shape
+    K, held = cfg.top_k, cfg.experts_held
+    with jax.named_scope("dispatch"):
+        e = topk_idx.reshape(T * K) - cfg.expert_offset
+        slot = jnp.where((e >= 0) & (e < held), e, held)  # absent experts sort last
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.bincount(slot, length=held + 1).astype(jnp.int32)
+        token = order // K  # the token of each (token, choice) row, in expert order
+        xs = xt[token]
+    with jax.named_scope("experts"):
+        h = _act(cfg.act)(_grouped_matmul(xs, p["w_gate_e"], sizes))
+        h = h * _grouped_matmul(xs, p["w_up_e"], sizes)
+        ys = _grouped_matmul(h, p["w_down_e"], sizes)
+    with jax.named_scope("combine"):
+        w = jnp.where(slot < held, topk_p.reshape(T * K), 0.0)[order].astype(xt.dtype)
+        out = jnp.zeros((T, D), xt.dtype).at[token].add(ys * w[:, None])
+    return out, sizes
+
+
+def _in_blocks(a: jnp.ndarray, blocks: int, rows: int, fill=0) -> jnp.ndarray:
+    """Rows of ``a`` as ``(blocks, rows, ...)``, the last block filled out
+    with ``fill`` rows."""
+    extra = blocks * rows - a.shape[0]
+    if extra:
+        a = jnp.pad(a, ((0, extra),) + ((0, 0),) * (a.ndim - 1), constant_values=fill)
+    return a.reshape(blocks, rows, *a.shape[1:])
+
+
+def apply_held_moe(p: Params, x: jnp.ndarray, cfg):
+    """x: (B, S, D) -> (out, aux_loss, counts) for this chip's experts
+    ``[expert_offset, expert_offset + experts_held)`` of ``num_experts``.
+
+    The router scores every expert (softmax, greedy top-k, renormalised only
+    under ``norm_topk_prob``); the token-choices that went to a held expert
+    are sorted into one group per expert and run through grouped matmuls,
+    every one of them (no capacity, no drops), in equal blocks of at most
+    ``DISPATCH_TOKENS`` tokens; padding rows fill the last block and choose
+    no held expert.  The others add nothing here.  ``counts`` holds this
+    layer's token-choices routed to held experts and the largest held
+    expert's load.
+    """
+    B, S, D = x.shape
+    T, K, held = B * S, cfg.top_k, cfg.experts_held
+    xt = x.reshape(T, D)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        topk_p, topk_idx = jax.lax.top_k(probs, K)
+        if cfg.norm_topk_prob:
+            topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+        aux = _sequence_balance_loss(probs.reshape(B, S, -1), topk_idx.reshape(B, S, K), K)
+    blocks = -(-T // DISPATCH_TOKENS)
+    rows = -(-T // (blocks * ROW_TILE)) * ROW_TILE
+    experts = {k: p[k] for k in ("w_gate_e", "w_up_e", "w_down_e")}
+    part = jax.checkpoint(lambda args: _held_part(experts, *args, cfg))
+    out, sizes = jax.lax.map(part, (
+        _in_blocks(xt, blocks, rows), _in_blocks(topk_p, blocks, rows),
+        _in_blocks(topk_idx, blocks, rows, fill=-1)))  # padding chooses no held expert
+    out, sizes = out.reshape(blocks * rows, D)[:T].reshape(B, S, D), jnp.sum(sizes, axis=0)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            out = out + _shared(p["shared"], x, cfg)
+    counts = {"moe_held_choices": jnp.sum(sizes[:held]), "moe_max_expert_load": jnp.max(sizes[:held])}
+    return out, aux, counts

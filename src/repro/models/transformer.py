@@ -4,7 +4,9 @@ A config's ``block_pattern`` (e.g. gemma3's 5×local + 1×global, or
 recurrentgemma's recurrent/recurrent/local) defines one *period*; the stack is
 ``num_periods`` scanned repetitions of the period (params stacked on a leading
 axis, MaxText-style, for O(period) compile time) plus unrolled remainder
-layers.  Every block is pre-norm residual; gemma2/3 add post-norms.
+layers.  A config with ``first_k_dense_replace`` puts that many dense-MLP
+layers in front (deepseek-v2), stacked and scanned the same way.  Every
+block is pre-norm residual; gemma2/3 add post-norms.
 
 Each layer type owns its decode cache:
   global     -> full KV cache (capacity = max sequence)
@@ -47,10 +49,14 @@ def _norm(cfg, p, x):
     return fn(p, x, cfg.norm_eps)
 
 
-def init_block(key, layer_type: str, cfg) -> Params:
+def init_block(key, layer_type: str, cfg, *, dense: bool = False) -> Params:
+    """``dense``: a dense MLP even where the config has experts (the leading
+    layers of ``first_k_dense_replace``)."""
     ks = jax.random.split(key, 4)
     p: Params = {"pre_norm": _norm_init(cfg)}
-    if layer_type in ("global", "local"):
+    if layer_type in ("global", "local") and cfg.kv_lora_rank:
+        p["attn"] = A.init_mla(ks[0], cfg)
+    elif layer_type in ("global", "local"):
         p["attn"] = A.init_attention(ks[0], cfg)
     elif layer_type == "ssm":
         p["ssm"] = S.init_ssm(ks[0], cfg)
@@ -62,7 +68,7 @@ def init_block(key, layer_type: str, cfg) -> Params:
     if cfg.use_post_norms:
         p["post_norm"] = _norm_init(cfg)
     p["mlp_pre_norm"] = _norm_init(cfg)
-    if cfg.num_experts:
+    if cfg.num_experts and not dense:
         p["moe"] = MOE.init_moe(ks[1], cfg)
     else:
         p["mlp"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff, cfg.gated_mlp)
@@ -75,23 +81,47 @@ def _window_for(layer_type: str, cfg) -> int | None:
     return cfg.window_size if layer_type == "local" else None
 
 
-def apply_block(p: Params, x: jnp.ndarray, layer_type: str, io: LayerIO, cfg):
-    """Full-sequence (train/prefill-without-cache) path -> (x, aux_loss)."""
+def zero_stats(cfg) -> dict:
+    """What a stack's layers add up besides the residual stream: the router
+    load-balance loss (``aux``), and for a chip's share of experts its
+    counters (token-choices routed to held experts, summed over layers;
+    the largest held expert's load, the max over layers)."""
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.experts_held:
+        stats["moe_held_choices"] = jnp.zeros((), jnp.int32)
+        stats["moe_max_expert_load"] = jnp.zeros((), jnp.int32)
+    return stats
+
+
+def add_stats(total: dict, new: dict) -> dict:
+    out = dict(total)
+    for k, v in new.items():
+        out[k] = jnp.maximum(total[k], v) if k == "moe_max_expert_load" else total[k] + v
+    return out
+
+
+def apply_block(p: Params, x: jnp.ndarray, layer_type: str, io: LayerIO, cfg, *, dense: bool = False):
+    """Full-sequence (train/prefill-without-cache) path -> (x, stats), the
+    stats a dict of :func:`zero_stats`' keys (``aux`` alone unless the
+    block holds a share of experts)."""
     from repro.sharding.ctx import shard_activation
 
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     if cfg.sequence_parallel:
         # residual stream seq-sharded over `model` between mixers (Megatron
         # SP): norms/elementwise run on 1/|model| of the tokens, XLA places
         # all-gather before q/k/v and reduce-scatter after wo / w_down.
         x = shard_activation(x, ("batch", "seq_sp", None))
     pre = _norm(cfg, p["pre_norm"], x)
-    if layer_type in ("global", "local"):
+    if layer_type in ("global", "local") and cfg.kv_lora_rank:
+        with jax.named_scope("mla"):
+            h = A.mla_layer(p["attn"], pre, io, cfg)
+    elif layer_type in ("global", "local"):
         h = A.attention_layer(p["attn"], pre, io, cfg, window=_window_for(layer_type, cfg),
                               use_rope=cfg.use_rope)
     elif layer_type == "ssm":
         h = S.apply_ssm(p["ssm"], pre, cfg)
-        return x + h, aux
+        return x + h, stats
     elif layer_type == "recurrent":
         h = R.apply_rglru(p["rglru"], pre, cfg)
     if cfg.use_post_norms:
@@ -102,8 +132,12 @@ def apply_block(p: Params, x: jnp.ndarray, layer_type: str, io: LayerIO, cfg):
     else:
         x = x + h
         m_in = _norm(cfg, p["mlp_pre_norm"], x)
-    if cfg.num_experts:
-        m, aux = MOE.apply_moe(p["moe"], m_in, cfg)
+    if cfg.num_experts and not dense and cfg.experts_held:
+        with jax.named_scope("moe"):
+            m, aux, counts = MOE.apply_held_moe(p["moe"], m_in, cfg)
+        stats = {"aux": aux, **counts}
+    elif cfg.num_experts and not dense:
+        m, stats["aux"] = MOE.apply_moe(p["moe"], m_in, cfg)
     else:
         m = apply_mlp(p["mlp"], m_in, cfg.act)
     if cfg.use_post_norms:
@@ -111,7 +145,7 @@ def apply_block(p: Params, x: jnp.ndarray, layer_type: str, io: LayerIO, cfg):
     x = (x + h + m) if cfg.parallel_residual else (x + m)
     if cfg.sequence_parallel:
         x = shard_activation(x, ("batch", "seq_sp", None))
-    return x, aux
+    return x, stats
 
 
 # ---------------------------------------------------------------------------
@@ -213,10 +247,25 @@ def prefill_block_cache(p: Params, x: jnp.ndarray, layer_type: str, io: LayerIO,
 # Stack: scan over periods + unrolled remainder
 # ---------------------------------------------------------------------------
 
+def _require_decodable(cfg):
+    if cfg.kv_lora_rank or cfg.first_k_dense_replace:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention and leading dense layers run on the "
+            "full-sequence (training) path only; there is no decode cache for them"
+        )
+
+
 def init_stack(key, cfg) -> Params:
+    """Params of the stack: ``lead`` (the ``first_k_dense_replace`` dense
+    layers, stacked), ``pos{j}`` (the pattern's periods, stacked) or
+    ``layer{i}``, and ``rem{i}`` (remainder layers)."""
     pattern = cfg.block_pattern
     n_per = cfg.num_periods
     params: Params = {}
+    if cfg.first_k_dense_replace:
+        keys = jax.random.split(jax.random.fold_in(key, 30_000), cfg.first_k_dense_replace)
+        layers = [init_block(k, "global", cfg, dense=True) for k in keys]
+        params["lead"] = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
     if cfg.scan_layers and n_per > 0:
         for j, t in enumerate(pattern):
             keys = jax.random.split(jax.random.fold_in(key, j), n_per)
@@ -231,32 +280,43 @@ def init_stack(key, cfg) -> Params:
 
 
 def apply_stack(params: Params, x: jnp.ndarray, io: LayerIO, cfg):
+    """-> (x, stats): the summed stats of :func:`zero_stats`."""
     pattern = cfg.block_pattern
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = zero_stats(cfg)
+
+    if cfg.first_k_dense_replace:
+        def lead(carry, layer_params):
+            x, st = carry
+            x, a = apply_block(layer_params, x, "global", io, cfg, dense=True)
+            return (x, add_stats(st, a)), None
+
+        body = jax.checkpoint(lead) if cfg.remat else lead
+        (x, stats), _ = jax.lax.scan(body, (x, stats), params["lead"])
 
     if cfg.scan_layers and cfg.num_periods > 0:
         stacked = {f"pos{j}": params[f"pos{j}"] for j in range(len(pattern))}
 
         def period(carry, period_params):
-            x, aux = carry
+            x, st = carry
             for j, t in enumerate(pattern):
                 x, a = apply_block(period_params[f"pos{j}"], x, t, io, cfg)
-                aux = aux + a
-            return (x, aux), None
+                st = add_stats(st, a)
+            return (x, st), None
 
         body = jax.checkpoint(period) if cfg.remat else period
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), stacked)
+        (x, stats), _ = jax.lax.scan(body, (x, stats), stacked)
     else:
         for i, t in enumerate(pattern * cfg.num_periods):
             x, a = apply_block(params[f"layer{i}"], x, t, io, cfg)
-            aux_total = aux_total + a
+            stats = add_stats(stats, a)
     for i, t in enumerate(cfg.remainder_layers):
         x, a = apply_block(params[f"rem{i}"], x, t, io, cfg)
-        aux_total = aux_total + a
-    return x, aux_total
+        stats = add_stats(stats, a)
+    return x, stats
 
 
 def init_stack_cache(cfg, batch: int, capacity: int, dtype) -> Params:
+    _require_decodable(cfg)
     pattern = cfg.block_pattern
     cache: Params = {}
     if cfg.scan_layers and cfg.num_periods > 0:
@@ -274,6 +334,7 @@ def init_stack_cache(cfg, batch: int, capacity: int, dtype) -> Params:
 
 
 def apply_stack_step(params: Params, x: jnp.ndarray, cache, pos, cfg):
+    _require_decodable(cfg)
     pattern = cfg.block_pattern
     if cfg.scan_layers and cfg.num_periods > 0:
         stacked_p = {f"pos{j}": params[f"pos{j}"] for j in range(len(pattern))}
@@ -304,6 +365,7 @@ def prefill_stack(params: Params, x: jnp.ndarray, io: LayerIO, cfg, capacity: in
 
     The scanned path threads the cache as scan outputs (stacked per period).
     """
+    _require_decodable(cfg)
     pattern = cfg.block_pattern
 
     if cfg.scan_layers and cfg.num_periods > 0:

@@ -133,6 +133,7 @@ class _EngineBase:
         self.mesh = spec.mesh
         self._traces: list[int] = []
         self._tick: Callable | None = None
+        self._params_released = False
 
     @property
     def retraces(self) -> int | None:
@@ -177,7 +178,21 @@ class _EngineBase:
         raise NotImplementedError
 
     def build(self):
-        return self._own(self._build(jax.random.PRNGKey(self.spec.seed)))
+        """The initial state.  A donated fused state owns all its buffers
+        (:meth:`_own`), so the engine then lets go of ``spec.params``: held
+        for the whole run they would keep a second full-width copy of the
+        weights on the device.  The caller's spec keeps them; a further
+        build of this engine raises (make a new engine from the spec)."""
+        if self._params_released:
+            raise RuntimeError(
+                "this engine already built its state from spec.params and released "
+                "them; build a new engine from the spec (make_engine(spec))"
+            )
+        state = self._own(self._build(jax.random.PRNGKey(self.spec.seed)))
+        if self._donate_state and self.spec.fuse and self.spec.params is not None:
+            self.spec = dataclasses.replace(self.spec, params=None)
+            self._params_released = True
+        return state
 
     def build_template(self):
         """Shape/dtype-only build for checkpoint restore: the resume path

@@ -249,3 +249,31 @@ def test_update_launch_keeps_its_name(one_chip):
     launches = re.findall(r"^\s*(?:ROOT )?(%\S+) = .*tpu_custom_call", text, re.M)
     assert launches
     assert all(UPDATE_KERNEL.search(name) for name in launches), launches
+
+
+def test_held_expert_layer_compiles_its_grouped_matmuls(one_chip, monkeypatch):
+    """One expert layer of the DeepSeek-V2-Lite cell (2 x 4096 tokens, top-6
+    of 64 experts, 8 held, d_model 2048, experts 1408 wide), forward and
+    backward as the tick differentiates it: on the TPU path its grouped
+    matmuls are Pallas launches (the megablox ``gmm`` and, for the weight
+    gradients, ``tgmm``), and they fit."""
+    import dataclasses
+
+    import repro.kernels
+    from repro.configs import get_config
+    from repro.models import moe as MOE
+
+    monkeypatch.setattr(repro.kernels, "ON_TPU", True)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), experts_held=8)
+    params = jax.eval_shape(lambda k: MOE.init_moe(k, cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), params)
+    x = _sds((2, 4096, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        out, aux, _ = MOE.apply_held_moe(p, x, cfg)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 9  # 3 forward, 6 backward
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
